@@ -1253,8 +1253,11 @@ def _run_kernels_sharded(point, ctx):
         import subprocess
         worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                               "sharded_collectives.py")
+        # the worker counts bytes on virtual CPU devices: it never competes
+        # for an accelerator this process may hold
         r = subprocess.run([sys.executable, worker], capture_output=True,
-                           text=True, timeout=560)
+                           text=True, timeout=560,
+                           env={**os.environ, "JAX_PLATFORMS": "cpu"})
         if r.returncode != 0:
             raise RuntimeError(
                 f"sharded_collectives worker failed:\n{r.stderr}")

@@ -690,7 +690,6 @@ def _shard_flat_ops(plan, local):
     shardings, so no resharding collective can appear in the local step).
     The client axis keeps its tree-path semantics: the M dim rides the plan's
     client entry; per-client ``t`` is sharded over it."""
-    from jax.experimental.shard_map import shard_map
     from repro.kernels import ops as kops
     mesh, lay, cl_entry = plan.mesh, plan.layout, plan.client
     lead_m = (cl_entry,)
@@ -725,8 +724,8 @@ def _shard_flat_ops(plan, local):
             return (po, mo, do) if update_d else (po, mo)
 
         out_specs = (fs_m,) * (3 if update_d else 2)
-        outs = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                         out_specs=out_specs, check_rep=False)(*operands)
+        outs = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                             out_specs=out_specs, check_vma=False)(*operands)
         return outs[0], outs[1], (outs[2] if update_d else None)
 
     return flat_m, unflat_m, flat_d, unflat_d, fused_step

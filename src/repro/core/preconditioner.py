@@ -109,17 +109,19 @@ def update(cfg: PrecondConfig, state, stat):
     if cfg.kind == "identity":
         return {"t": state["t"] + 1}
     t = state["t"]
-    if cfg.kind == "adagrad":
-        d = jax.tree.map(lambda d2, h2: d2 + h2, state["d"], stat)
-    elif cfg.rule == "squared":
-        b = beta_t(cfg, t)
-        d = jax.tree.map(lambda d2, h2: b * d2 + (1.0 - b) * h2,
-                         state["d"], stat)
-    else:  # linear (OASIS)
-        b = beta_t(cfg, t)
-        d = jax.tree.map(lambda dd, h: b * dd + (1.0 - b) * h,
-                         state["d"], stat)
+    b = beta_t(cfg, t)
+    d = jax.tree.map(lambda dd, h: ema(cfg, b, dd, h), state["d"], stat)
     return {"d": d, "t": t + 1}
+
+
+def ema(cfg: PrecondConfig, b, d, stat):
+    """One leaf of the D update with β_{t+1} = ``b`` (None for AdaGrad).
+
+    Rule (2) keeps D² and rule (3) keeps D, so both are the same EMA of the
+    stored quantity; AdaGrad accumulates without decay."""
+    if cfg.kind == "adagrad":
+        return d + stat
+    return b * d + (1.0 - b) * stat
 
 
 def dhat(cfg: PrecondConfig, state, leaf_of=None):

@@ -25,7 +25,8 @@ would pick shape-dependent accumulation orders; see tests/test_serve.py).
 VMEM note: ``decode_attention`` holds one slot's full KV in VMEM — C·D·8
 bytes fp32 per (k, v); fine up to the LONG_DECODE_WINDOW ring (8192·64·4·2
 ≈ 4 MiB) but not for an unwindowed 500k cache — long contexts must decode
-through ``decode_window``.
+through ``decode_window``. ``decode_sample`` sizes its vocab block so one
+table tile stays within ``TABLE_BLOCK_BYTES`` (``vocab_block``).
 """
 from __future__ import annotations
 
@@ -39,10 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import ref as kref
 
 NEG_INF = -1e30
-
-# jax renamed TPUCompilerParams -> CompilerParams across versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+TABLE_BLOCK_BYTES = 4 << 20   # one buffer of decode_sample's table tile
 
 
 # --------------------------------------------------------------------------- #
@@ -51,38 +49,43 @@ _CompilerParams = getattr(pltpu, "CompilerParams",
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, *, softcap):
-    q = q_ref[0, 0]            # (rep, D)
-    k = k_ref[0, :, 0, :]      # (C, D)
-    v = v_ref[0, :, 0, :]      # (C, Dv)
-    bias = b_ref[0]            # (C,)
-    o_ref[0, 0] = kref.decode_attention_math(q, k, v, bias, softcap)
+    o_ref[...] = kref.decode_attention_math(q_ref[...], k_ref[...],
+                                            v_ref[...], b_ref[0], softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
 def decode_attention(q, k, v, bias, *, softcap=0.0, interpret=False):
     """q (B,H,D), k/v (B,C,Hk,D/Dv) cache layout, bias (B,C) fp32 additive
-    mask -> (B,H,Dv) fp32."""
+    mask -> (B,H,Dv) fp32.
+
+    The cache is read head-major, (B,Hk,C,D): one grid cell then holds a
+    (C, D) slab, which meets Mosaic's tiling rule (the last two block dims
+    must be multiples of (8, 128) or whole dims) for any Hk, where a (1, D)
+    slice of the (Hk, D) minor dims does not."""
     B, H, D = q.shape
     C, Hk = k.shape[1], k.shape[2]
     Dv = v.shape[3]
     rep = H // Hk
     qr = q.reshape(B, Hk, rep, D)
+    kh = k.transpose(0, 2, 1, 3)
+    vh = v.transpose(0, 2, 1, 3)
     kern = functools.partial(_attn_kernel, softcap=softcap)
+    cell = lambda b, h: (b, h, 0, 0)
     out = pl.pallas_call(
         kern,
         grid=(B, Hk),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, C, 1, D), lambda b, h: (b, 0, h, 0)),
-            pl.BlockSpec((1, C, 1, Dv), lambda b, h: (b, 0, h, 0)),
-            pl.BlockSpec((1, C), lambda b, h: (b, 0)),
+            pl.BlockSpec((None, None, rep, D), cell),
+            pl.BlockSpec((None, None, C, D), cell),
+            pl.BlockSpec((None, None, C, Dv), cell),
+            pl.BlockSpec((None, 1, C), lambda b, h: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, Dv), lambda b, h: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, rep, Dv), cell),
         out_shape=jax.ShapeDtypeStruct((B, Hk, rep, Dv), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(qr, k, v, bias)
+    )(qr, kh, vh, bias.reshape(B, 1, C))
     return out.reshape(B, H, Dv)
 
 
@@ -114,18 +117,33 @@ def _sample_kernel(y_ref, t_ref, n_ref, best_ref, arg_ref, *, blk, v_real,
         best_ref[0] = jnp.where(upd, m, prev)
 
 
+def vocab_block(d: int, itemsize: int, *, max_block=2048,
+                budget=TABLE_BLOCK_BYTES) -> int:
+    """Largest power-of-two vocab block up to ``max_block`` whose (block, d)
+    table tile fits ``budget`` bytes. The tile is double-buffered, so at
+    d=896 fp32 a 2048 block (7.3 MB, twice) overruns v5e's 16 MiB scoped
+    VMEM where 1024 fits."""
+    block = max_block
+    while block > 8 and block * d * itemsize > budget:
+        block //= 2
+    return block
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "v_real", "block",
                                              "interpret"))
-def decode_sample(y, table, noise, *, scale, v_real, block=2048,
+def decode_sample(y, table, noise, *, scale, v_real, block=None,
                   interpret=False):
     """y (B,d) final hidden, table (V,d), noise (B,V) fp32 -> token ids (B,).
 
     token[b] = argmax_v<v_real (y[b]·table[v])*scale + noise[b,v]. The vocab
     grid is sequential ("arbitrary"): a running (best, arg) pair lives in the
-    output blocks across vocab steps.
+    output blocks across vocab steps. ``block`` defaults to ``vocab_block``;
+    the token does not depend on it (first-index ties either way).
     """
     B, d = y.shape
     V = table.shape[0]
+    if block is None:
+        block = vocab_block(d, table.dtype.itemsize)
     block = min(block, V)
     assert V % block == 0, (V, block)
     kern = functools.partial(_sample_kernel, blk=block, v_real=v_real,
@@ -142,7 +160,8 @@ def decode_sample(y, table, noise, *, scale, v_real, block=2048,
                    pl.BlockSpec((1, B), lambda j: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, B), jnp.float32),
                    jax.ShapeDtypeStruct((1, B), jnp.int32)],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(y, table, noise)
     return arg[0]

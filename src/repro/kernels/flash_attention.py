@@ -106,10 +106,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0, softcap=0.0,
             pltpu.VMEM((bq, 1), jnp.float32),    # running sum l
             pltpu.VMEM((bq, D), jnp.float32),    # output accumulator
         ],
-        # jax renamed TPUCompilerParams -> CompilerParams across versions;
-        # take whichever this jaxlib ships
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,12 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) every kernel runs in interpret mode — the kernel body
+On the CPU backend every kernel runs in interpret mode — the kernel body
 executes in Python on CPU, which is the validation path; on TPU the same calls
-compile to Mosaic. ``REPRO_PALLAS_INTERPRET=0/1`` overrides autodetection.
+compile to Mosaic. Any other backend is an error (``_interpret``).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,10 +18,13 @@ from repro.utils.tree import tree_from_paths
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
-    return jax.default_backend() == "cpu"
+    """Interpret mode on the CPU backend, Mosaic on the TPU, an error
+    anywhere else: a kernel never runs interpreted on an accelerator."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run on cpu (interpreted) or tpu, "
+                           f"not on backend {backend!r}")
+    return backend == "cpu"
 
 
 def scaled_update(p, m, g, d, *, gamma, beta1, alpha, squared=True):
@@ -107,12 +108,13 @@ def decode_attention(q, k, v, bias, *, softcap=0.0):
                                 interpret=_interpret())
 
 
-def decode_sample(y, table, noise, *, scale, v_real, block=2048):
+def decode_sample(y, table, noise, *, scale, v_real, block=None):
     """Fused unembed + gumbel-argmax sampling tail.
 
     y (B,d) final hidden; table (V,d); noise (B,V) fp32 (zeros = greedy).
     Returns token ids (B,) int32 without materialising the (B,V) logits —
-    bitwise-equal to ``ref.decode_sample_ref``.
+    bitwise-equal to ``ref.decode_sample_ref``. ``block=None`` sizes the
+    vocab block to VMEM (``decode_step.vocab_block``).
     """
     return _ds.decode_sample(y, table, noise, scale=float(scale),
                              v_real=int(v_real), block=block,

@@ -13,24 +13,41 @@ def scaled_update_ref(p, m, g, d, *, gamma, beta1, alpha, squared=True):
     return p - gamma * m_new / dhat, m_new
 
 
-def fused_step_math(p, m, g, d, h, t, s, *, gamma, beta1, weight_decay,
+def debias_beta(t, *, kind, beta2, schedule, update_d):
+    """Per-client debias factor β_t (M,) for step counters ``t`` (M,) i32,
+    or None when the step uses no step counter (global D, the const
+    schedule, AdaGrad, identity).
+
+    ``preconditioner.beta_t`` itself — the expression the tree path
+    evaluates — run in XLA outside the fused kernel, which takes the result
+    as a scalar-prefetch operand (Mosaic has no ``powf``)."""
+    if not (update_d and schedule == "debias"
+            and kind not in ("adagrad", "identity")):
+        return None
+    if t is None:
+        raise ValueError("debias schedule needs per-client t")
+    from repro.core import preconditioner as PC
+    cfg = PC.PrecondConfig(kind=kind, beta2=beta2, beta_schedule="debias")
+    return PC.beta_t(cfg, t)
+
+
+def fused_step_math(p, m, g, d, h, b, s, *, gamma, beta1, weight_decay,
                     alpha, beta2, kind, clip, schedule, update_d):
     """One generic-scaling local step — the paper's unified Assumption-4 rule.
 
     The single source of truth for the fused flat-buffer kernel
     (``scaled_update.fused_step_flat`` runs this per block; DESIGN.md §7).
     The D math itself is NOT re-implemented: this delegates to
-    ``preconditioner.update``/``dhat`` on the bare buffers (they are valid
-    single-leaf pytrees), so the fused kernel and the engine's unfused tree
-    path share one copy of the Assumption-4 formulas — which is what makes
-    the trajectories agree bitwise in fp32, and keeps a future rule/schedule
-    change from silently diverging.
+    ``preconditioner.ema``/``dhat`` on the bare buffers, so the fused kernel
+    and the engine's unfused tree path share one copy of the Assumption-4
+    formulas — which is what makes the trajectories agree bitwise in fp32,
+    and keeps a future rule/schedule change from silently diverging.
 
-    ``d``/``h``/``t``/``s`` may be None when the mode doesn't use them
-    (identity kind; in-kernel grad² stat; const schedule; no grad clip);
-    ``t``/``s`` must already broadcast against ``p`` (scalar in the kernel,
-    ``(M, 1)`` in the reference). Returns ``(p', m', d')`` with ``d'`` None
-    unless ``update_d``.
+    ``d``/``h``/``b``/``s`` may be None when the mode doesn't use them
+    (identity kind; in-kernel grad² stat; const schedule or AdaGrad; no grad
+    clip). ``b`` is the debias β_t (``debias_beta``); ``b``/``s`` must
+    already broadcast against ``p`` (``(M, 1)``). Returns ``(p', m', d')``
+    with ``d'`` None unless ``update_d``.
     """
     from repro.core import preconditioner as PC
     cfg = PC.PrecondConfig(kind=kind, beta2=beta2, alpha=alpha, clip=clip,
@@ -40,8 +57,9 @@ def fused_step_math(p, m, g, d, h, t, s, *, gamma, beta1, weight_decay,
     d_new = None
     if update_d:                        # local scaling: D advances every step
         stat = (g ** 2) if h is None else h   # grad_stat | external Hutchinson
-        tt = t if t is not None else jnp.int32(0)   # unused by const/adagrad
-        d_new = PC.update(cfg, {"d": d, "t": tt}, stat)["d"]
+        if b is None:                   # const / AdaGrad: no step counter
+            b = PC.beta_t(cfg, None)
+        d_new = PC.ema(cfg, b, d, stat)
         d = d_new
     if weight_decay:
         g = g + weight_decay * p
@@ -56,10 +74,12 @@ def fused_step_math(p, m, g, d, h, t, s, *, gamma, beta1, weight_decay,
 def fused_step_ref(p, m, g, d=None, h=None, t=None, s=None, *, gamma, beta1,
                    weight_decay=0.0, alpha, beta2=0.999, kind, clip="max",
                    schedule="const", update_d=False):
-    """(M, n) reference for the fused kernel: per-row t/s broadcast over n."""
-    t2 = None if t is None else t[:, None]
+    """(M, n) reference for the fused kernel: per-row β_t/s broadcast over n."""
+    b = debias_beta(t, kind=kind, beta2=beta2, schedule=schedule,
+                    update_d=update_d)
+    b = None if b is None else b[:, None]
     s2 = None if s is None else s[:, None]
-    return fused_step_math(p, m, g, d, h, t2, s2, gamma=gamma, beta1=beta1,
+    return fused_step_math(p, m, g, d, h, b, s2, gamma=gamma, beta1=beta1,
                            weight_decay=weight_decay, alpha=alpha, beta2=beta2,
                            kind=kind, clip=clip, schedule=schedule,
                            update_d=update_d)

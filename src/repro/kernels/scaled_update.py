@@ -11,15 +11,15 @@ Two generations live here:
   whole generic-scaling local step of the paper's unified Assumption-4 rule
   in ONE pass over the per-client flat buffer ``(M, n)``.  Fuses the D̂
   update — rule-2 squared EMA (Adam/RMSProp), rule-3 linear EMA (OASIS),
-  AdaGrad accumulate, β_t const or Adam-debias (``t`` rides as a scalar
-  prefetch) — together with the momentum + scaled parameter update, for
-  every ``PrecondConfig`` kind including identity.  Per element that is
-  4–5 HBM reads (p, m, g, d[, h]) + 3 writes (p', m', d') where the per-leaf
-  path paid 6+ reads / 4 writes across three launches (momentum pass,
-  per-leaf kernel, separate D̂ EMA pass).  The grid is ``(M, n/BLOCK)`` so
-  one ``pallas_call`` covers every client's step; per-client scalars (step
-  counter ``t``, grad-clip scale ``s``) are scalar-prefetch operands indexed
-  by ``program_id(0)``.
+  AdaGrad accumulate, β_t const or Adam-debias (the per-client β_t rides as
+  a scalar prefetch) — together with the momentum + scaled parameter
+  update, for every ``PrecondConfig`` kind including identity.  Per element
+  that is 4–5 HBM reads (p, m, g, d[, h]) + 3 writes (p', m', d') where the
+  per-leaf path paid 6+ reads / 4 writes across three launches (momentum
+  pass, per-leaf kernel, separate D̂ EMA pass).  The grid walks lane blocks
+  of ``(M, blk)``, so one ``pallas_call`` covers every client's step;
+  per-client scalars (debias β_t, grad-clip scale ``s``) are scalar-prefetch
+  operands broadcast to an ``(M, 1)`` column.
 
 The kernel body calls ``ref.fused_step_math`` — the pure-jnp oracle is the
 single source of truth for the formula, and the engine's unfused tree path is
@@ -116,18 +116,26 @@ def scaled_update_flat(p, m, g, d, *, gamma, beta1, alpha, squared=True,
 # --------------------------------------------------------------------------- #
 
 
-def _fused_kernel(t_ref, s_ref, *refs, n_in, gamma, beta1, weight_decay,
+def _per_client(ref, M):
+    """(M,) scalar-prefetch operand -> (M, 1) column broadcasting over lanes."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0)
+    col = jnp.full((M, 1), ref[0], ref.dtype)
+    for i in range(1, M):
+        col = jnp.where(row == i, ref[i], col)
+    return col
+
+
+def _fused_kernel(b_ref, s_ref, *refs, n_in, M, gamma, beta1, weight_decay,
                   alpha, beta2, kind, clip, schedule, update_d, has_d,
-                  has_h, clipped, needs_t):
-    i = pl.program_id(0)
+                  has_h, clipped, prefetch_beta):
     it = iter(refs[:n_in])
     p, m, g = next(it)[...], next(it)[...], next(it)[...]
     d = next(it)[...] if has_d else None
     h = next(it)[...] if has_h else None
-    t = t_ref[i] if needs_t else None
-    s = s_ref[i] if clipped else None
+    b = _per_client(b_ref, M) if prefetch_beta else None
+    s = _per_client(s_ref, M) if clipped else None
     p_new, m_new, d_new = kref.fused_step_math(
-        p, m, g, d, h, t, s, gamma=gamma, beta1=beta1,
+        p, m, g, d, h, b, s, gamma=gamma, beta1=beta1,
         weight_decay=weight_decay, alpha=alpha, beta2=beta2, kind=kind,
         clip=clip, schedule=schedule, update_d=update_d)
     outs = refs[n_in:]
@@ -150,8 +158,15 @@ def fused_step_flat(p, m, g, d=None, h=None, t=None, s=None, *, gamma, beta1,
     Shapes: ``p/m/g`` (M, n) fp32; ``d`` (M, n) for local scaling, (n,) for
     global (client-shared D̂), None for the identity kind; ``h`` (M, n)
     external stat (Hutchinson kinds) or None for the in-kernel grad² stat;
-    ``t`` (M,) i32 per-client step counters (scalar prefetch; required for the
-    debias schedule); ``s`` (M,) f32 per-client grad-clip scales or None.
+    ``t`` (M,) i32 per-client step counters (required for the debias
+    schedule); ``s`` (M,) f32 per-client grad-clip scales or None.
+
+    Each grid step covers all M clients over one lane block: ``(M, blk)`` is
+    the tiling Mosaic accepts for any M (a block's second-minor dim must be a
+    multiple of 8 or the whole dim). The debias factor β_t is computed per
+    client here in XLA, by the tree path's own expression
+    (``ref.debias_beta``), and enters as a scalar-prefetch operand: the
+    kernel never raises to a power (Mosaic cannot lower ``powf``).
 
     Returns ``(p', m', d')`` with ``d'`` None unless ``update_d`` (which
     requires a local, (M, n)-shaped ``d``).
@@ -161,40 +176,43 @@ def fused_step_flat(p, m, g, d=None, h=None, t=None, s=None, *, gamma, beta1,
     has_h = h is not None
     global_d = has_d and d.ndim == 1
     clipped = s is not None
-    needs_t = update_d and schedule == "debias" and kind != "adagrad"
     if update_d and (not has_d or global_d):
         raise ValueError("update_d needs a per-client (M, n) d buffer")
-    if needs_t and t is None:
-        raise ValueError("debias schedule needs per-client t")
+    beta = kref.debias_beta(t, kind=kind, beta2=beta2, schedule=schedule,
+                            update_d=update_d)
+    prefetch_beta = beta is not None
 
-    blk = _block_for(n, block)
+    # one block spans every client row; keep it near BLOCK elements of
+    # (8-row padded) VMEM per buffer as M grows
+    rows = -(-M // 8) * 8
+    blk = _block_for(n, max(128, (block * 8 // rows) // 128 * 128))
     # no explicit padding: the tail block is partial and Pallas masks it
     # (see the module padding contract) — an explicit pad would copy every
     # operand per local step
     operands = [p, m, g]
-    row_spec = pl.BlockSpec((1, blk), lambda i, j, t_ref, s_ref: (i, j))
+    row_spec = pl.BlockSpec((M, blk), lambda j, b_ref, s_ref: (0, j))
     in_specs = [row_spec] * 3
     if has_d:
-        operands.append(d)
-        in_specs.append(pl.BlockSpec((blk,), lambda i, j, t_ref, s_ref: (j,))
+        operands.append(d.reshape(1, n) if global_d else d)
+        in_specs.append(pl.BlockSpec((1, blk), lambda j, b_ref, s_ref: (0, j))
                         if global_d else row_spec)
     if has_h:
         operands.append(h)
         in_specs.append(row_spec)
-    if t is None:
-        t = jnp.zeros((M,), jnp.int32)
+    if beta is None:
+        beta = jnp.zeros((M,), jnp.float32)
     if s is None:
         s = jnp.ones((M,), jnp.float32)
 
     n_out = 3 if update_d else 2
     kern = functools.partial(
-        _fused_kernel, n_in=len(operands), gamma=gamma, beta1=beta1,
+        _fused_kernel, n_in=len(operands), M=M, gamma=gamma, beta1=beta1,
         weight_decay=weight_decay, alpha=alpha, beta2=beta2, kind=kind,
         clip=clip, schedule=schedule, update_d=update_d, has_d=has_d,
-        has_h=has_h, clipped=clipped, needs_t=needs_t)
+        has_h=has_h, clipped=clipped, prefetch_beta=prefetch_beta)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(M, -(-n // blk)),
+        grid=(-(-n // blk),),
         in_specs=in_specs,
         out_specs=[row_spec] * n_out,
     )
@@ -202,8 +220,10 @@ def fused_step_flat(p, m, g, d=None, h=None, t=None, s=None, *, gamma, beta1,
         kern,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((M, n), jnp.float32)] * n_out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(t, s, *operands)
+    )(beta, s, *operands)
     po, mo = outs[0], outs[1]
     do = outs[2] if update_d else None
     return po, mo, do

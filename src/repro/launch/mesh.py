@@ -25,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devs)} — the "
             f"dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             f"=512 before any jax import")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return _auto_mesh(shape, axes, devs[:need])
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
@@ -34,4 +34,13 @@ def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     devs = jax.devices()
     if len(devs) < need:
         raise RuntimeError(f"need {need} devices, have {len(devs)}")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return _auto_mesh(shape, axes, devs[:need])
+
+
+def _auto_mesh(shape, axes, devices):
+    """A mesh whose axes GSPMD partitions (``AxisType.Auto``): the plans
+    place arrays with NamedShardings and let propagation do the rest.
+    ``jax.make_mesh`` defaults to Explicit axes, under which the engine's
+    reshapes of sharded client state are refused at trace time."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models import ModelCallConfig, build, sample_batch
+from repro.utils.compile_cache import enable_compile_cache
 
 
 # --------------------------------------------------------------------------- #
@@ -509,6 +510,10 @@ def main():
     ap.add_argument("--arrival-rate", type=float, default=0.5,
                     help="Poisson arrivals per decode step (trace modes)")
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[serve] device {dev.platform} {dev.device_kind} "
+          f"x{jax.device_count()}", flush=True)
     common = dict(reduced=not args.full, prompt_len=args.prompt_len,
                   gen_len=args.gen_len, decode_window=args.decode_window,
                   seed=args.seed, greedy=not args.no_greedy)

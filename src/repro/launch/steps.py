@@ -79,8 +79,11 @@ def build_train_step(arch: str, shape: ShapeConfig, mesh, *, mode: str = "auto",
                      objective: Optional[objectives.ObjectiveSpec] = None,
                      labeled_frac: float = 1.0,
                      personal: Optional[tuple] = None,
-                     use_fused_kernel: bool = False, seed: int = 0):
+                     use_fused_kernel: bool = False, seed: int = 0,
+                     n_layers: Optional[int] = None):
     cfg = get_config(arch, reduced=reduced)
+    if n_layers:                   # depth cut to one chip; widths kept
+        cfg = cfg.replace(n_layers=n_layers)
     plan, mode = _train_plan(arch, mesh, mode)
     if call is None:
         call = ModelCallConfig()

@@ -53,12 +53,17 @@ from repro.core import PrecondConfig, SavicConfig, engine, objectives, savic
 from repro.data import LMRoundLoader, TokenStream
 from repro.data import federated
 from repro.models import ModelCallConfig, build
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _parser():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the model to this many layers, widths kept "
+                         "(0 = the config's depth); sizes a published-width "
+                         "model to one chip's memory")
     ap.add_argument("--rounds", type=int, default=20)
     ap.add_argument("--h-local", type=int, default=4)
     ap.add_argument("--clients", type=int, default=4,
@@ -66,6 +71,10 @@ def _parser():
                          "from the client axes)")
     ap.add_argument("--batch", type=int, default=8, help="per-client batch")
     ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--data-rounds", type=int, default=0,
+                    help="train round r on data round r mod N: the clients "
+                         "revisit N rounds of data (0 = fresh data every "
+                         "round)")
     ap.add_argument("--mesh", default="none",
                     choices=["none", "debug", "production", "production-2pod"],
                     help="route the launch through steps.build_train_step on "
@@ -233,9 +242,46 @@ def _objective_spec(args) -> objectives.ObjectiveSpec:
         pseudo_threshold=args.pseudo_threshold)
 
 
+class TrainLog(list):
+    """The per-round records ``main`` returns, plus ``setup``: the device,
+    the model's depth, and the round step's compile time, compiled memory
+    and Pallas kernel count (``_compile``), and the devices' peak bytes."""
+
+    def __init__(self, setup):
+        super().__init__()
+        self.setup = setup
+
+
+def _compile(step, args):
+    """AOT-compile the round step, so round 0's wall time is the round's
+    own and compilation is reported as set-up."""
+    t = time.perf_counter()
+    compiled = step.lower(*args).compile()
+    info = {"compile_s": time.perf_counter() - t}
+    ma = compiled.memory_analysis()
+    if ma is not None:
+        info.update(argument_bytes=ma.argument_size_in_bytes,
+                    output_bytes=ma.output_size_in_bytes,
+                    alias_bytes=ma.alias_size_in_bytes,
+                    temp_bytes=ma.temp_size_in_bytes,
+                    peak_bytes=ma.peak_memory_in_bytes)
+    info["pallas_calls"] = compiled.as_text().count("tpu_custom_call")
+    return compiled, info
+
+
 def main(argv=None):
     args = _parser().parse_args(argv)
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[train] device {dev.platform} {dev.device_kind} "
+          f"x{jax.device_count()}", flush=True)
     cfg = get_config(args.arch, reduced=args.reduced)
+    if args.layers:
+        cfg = cfg.replace(n_layers=args.layers)
+    log = TrainLog({"device": {"platform": dev.platform,
+                               "kind": dev.device_kind,
+                               "count": jax.device_count()},
+                    "n_layers": cfg.n_layers})
     call = ModelCallConfig(dtype=getattr(jnp, args.dtype))
     mesh = _make_mesh(args)
 
@@ -273,9 +319,11 @@ def main(argv=None):
             args.arch, shape, mesh, mode=args.mode, engine_spec=spec,
             reduced=args.reduced, h_local=args.h_local, call=call,
             objective=_objective_spec(args), labeled_frac=args.labeled_frac,
-            seed=args.seed + 1)
+            seed=args.seed + 1, n_layers=args.layers or None)
         spec = built.meta["engine_spec"]   # fused fallback may have applied
         if "fused_kernel_fallback" in built.meta:
+            log.setup["fused_kernel_fallback"] = \
+                built.meta["fused_kernel_fallback"]
             print(f"[train] fused kernel fallback: "
                   f"{built.meta['fused_kernel_fallback']}", flush=True)
         state_shardings, batch_shardings = built.in_shardings
@@ -284,17 +332,21 @@ def main(argv=None):
                          donate_argnums=built.donate)
         print(f"[train] mesh {dict(mesh.shape)} mode={built.meta['mode']} "
               f"M={M} b_client={args.batch} devices={mesh.size}", flush=True)
-        run_step = lambda state, batch, r: jitted(state, batch)
+        step_args = lambda state, batch, r: (state, batch)
         put_batch = lambda nb: jax.device_put(nb, batch_shardings)
     else:
         client_obj = objectives.build_objective(_objective_spec(args),
                                                 model=model)
-        round_step = jax.jit(engine.build_round_step(model.loss, spec,
-                                                     objective=client_obj))
+        # the state is donated, as on the mesh path: without it the old and
+        # the new state are live at once (7.5 GB each for 16 layers of
+        # qwen2-0.5b at M=2, which with the temporaries overflows a v5e)
+        jitted = jax.jit(engine.build_round_step(model.loss, spec,
+                                                 objective=client_obj),
+                         donate_argnums=0)
         root = jax.random.PRNGKey(args.seed + 1)
         # fold_in(root, r), NOT sequential splits from process start: a
         # restored run replays exactly round r's key (DESIGN.md §9)
-        run_step = lambda state, batch, r: round_step(
+        step_args = lambda state, batch, r: (
             state, batch, jax.random.fold_in(root, r))
         put_batch = lambda nb: jax.tree.map(jnp.asarray, nb)
 
@@ -311,16 +363,27 @@ def main(argv=None):
     loader = LMRoundLoader(stream, M, args.batch,
                            labeled_frac=args.labeled_frac, seed=args.seed)
     tokens_round = M * args.h_local * args.batch * args.seq
-    log = []
+    compiled = None
     t0 = time.time()
     with mesh if mesh is not None else contextlib.nullcontext():
         for r in range(start_round, args.rounds):
-            nb = loader.round_batch(r, args.h_local, args.seq)
+            nb = loader.round_batch(r % args.data_rounds if args.data_rounds
+                                    else r, args.h_local, args.seq)
             if cfg.family in ("audio", "vlm"):
                 nb = _wrap_modal(cfg, nb, args.seed, r)
             batch = put_batch(nb)
+            call_args = step_args(state, batch, r)
+            if compiled is None:
+                compiled, info = _compile(jitted, call_args)
+                log.setup.update(info)
+                print(f"[train] round step compiled in "
+                      f"{info['compile_s']:.1f}s: "
+                      + "".join(f"{k} {info[k] / 1e9:.3f} GB, " for k in
+                                ("argument_bytes", "temp_bytes", "peak_bytes")
+                                if k in info)
+                      + f"{info['pallas_calls']} Pallas calls", flush=True)
             tw = time.perf_counter()
-            state, metrics = run_step(state, batch, r)
+            state, metrics = compiled(*call_args)
             loss = float(metrics["loss"])          # blocks on the round
             wall = time.perf_counter() - tw
             drift = float(metrics["client_drift"])
@@ -356,9 +419,13 @@ def main(argv=None):
             rec["tokens_per_s"] = round(tokens_round / wall, 1)
             log.append(rec)
             print(f"[train] round {r:4d} loss {loss:.4f} drift {drift:.3e}"
-                  f"{extra} ({time.time()-t0:.1f}s)", flush=True)
+                  f"{extra} {wall:.3f}s/round {rec['tokens_per_s']:.0f} "
+                  f"tok/s ({time.time()-t0:.1f}s)", flush=True)
             if args.ckpt and (r + 1) % args.ckpt_every == 0:
                 ckpt_lib.save(args.ckpt, r + 1, state)
+    log.setup["peak_bytes_in_use"] = [
+        (d.memory_stats() or {}).get("peak_bytes_in_use")
+        for d in jax.local_devices()]
     if args.ckpt:
         ckpt_lib.save(args.ckpt, args.rounds, state)
     if args.log:

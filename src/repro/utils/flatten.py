@@ -29,7 +29,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.utils.tree import tree_paths
@@ -227,9 +226,9 @@ class ShardFlatLayout:
         buffer ``(*batch, n_flat)`` — each device flattens only its local
         shards; no cross-device traffic."""
         bd = len(lead)
-        f = shard_map(lambda t: self.local.flatten(t, batch_dims=bd),
-                      mesh=mesh, in_specs=(self.leaf_specs(lead),),
-                      out_specs=self.flat_spec(lead), check_rep=False)
+        f = jax.shard_map(lambda t: self.local.flatten(t, batch_dims=bd),
+                          mesh=mesh, in_specs=(self.leaf_specs(lead),),
+                          out_specs=self.flat_spec(lead), check_vma=False)
         return f(tree)
 
     def unflatten(self, buf, mesh, lead=()):
@@ -237,9 +236,9 @@ class ShardFlatLayout:
         its local shards (replicated-in-block leaves agree bit-for-bit across
         shards by construction: same elementwise math on identical inputs)."""
         bd = len(lead)
-        f = shard_map(lambda b: self.local.unflatten(b, batch_dims=bd),
-                      mesh=mesh, in_specs=(self.flat_spec(lead),),
-                      out_specs=self.leaf_specs(lead), check_rep=False)
+        f = jax.shard_map(lambda b: self.local.unflatten(b, batch_dims=bd),
+                          mesh=mesh, in_specs=(self.flat_spec(lead),),
+                          out_specs=self.leaf_specs(lead), check_vma=False)
         return f(buf)
 
     # ---- mesh-free reference (tests + differential oracle) ---------------- #

@@ -20,7 +20,7 @@ state/batch shardings on the same (2, 4) = ('data', 'model') mesh.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+os.environ["JAX_PLATFORMS"] = "cpu"   # 8 virtual CPU devices, kernels interpreted
 
 import json
 import sys

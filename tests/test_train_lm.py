@@ -119,6 +119,30 @@ def test_resume_bitwise_loss_state_log(tmp_path):
             assert fa.read() == fb.read(), fname
 
 
+def test_data_rounds_revisits_data():
+    """--data-rounds 1 trains every round on round 0's data: round 0 is the
+    fresh run's round 0 bitwise, and the loss then falls every round."""
+    from repro.launch import train as train_mod
+    fresh = train_mod.main(BASE + ["--rounds", "1"])
+    log = train_mod.main(BASE + ["--rounds", "3", "--data-rounds", "1"])
+    assert _det(log[0]) == _det(fresh[0])
+    losses = [r["loss"] for r in log]
+    assert losses[2] < losses[1] < losses[0], losses
+
+
+def test_layers_cuts_depth_and_reports_setup():
+    """--layers cuts depth with widths kept; the log's setup names the
+    device, the depth and the compiled round step."""
+    from repro.launch import train as train_mod
+    log = train_mod.main(BASE + ["--rounds", "1", "--layers", "1"])
+    s = log.setup
+    assert s["n_layers"] == 1
+    assert s["device"]["platform"] == "cpu" and s["device"]["count"] >= 1
+    assert s["compile_s"] > 0 and s["argument_bytes"] > 0
+    assert s["pallas_calls"] == 0          # interpret mode on the CPU
+    assert "fused_kernel_fallback" not in s
+
+
 @pytest.mark.slow
 def test_resume_bitwise_10_rounds(tmp_path):
     """The contract at the issue's full length: train(10) == train(5)+train(5)."""
