@@ -1,0 +1,10 @@
+"""Device ms a round under the named scope ``lm_head``:
+``models/layers.py:unembed`` and ``cross_entropy``, forward and
+backward; a part of ``model``. Read by ``scopes.read`` from the
+traced window and the compiled step's text. Moves
+``train_tokens_per_s``."""
+from benchmarks.chip import scopes
+
+
+def read(ctx):
+    return scopes.read(ctx, "lm_head")

@@ -600,18 +600,20 @@ def _client_loop(loss_fn, grad_fn, spec: EngineSpec, shard_plan=None,
 
     def local_step_one_client(params, mom, pstate, micro, key):
         """One scaled step on one client. pstate: the client's view of D."""
-        if semi:
-            loss, grads = obj_grad(params, micro, key)
-        else:
-            loss, grads = grad_fn(params, micro)
-        grads = _clip(grads, cl.grad_clip)
-        if cl.scaling == "local" and pc.kind != "identity":
-            stat = (PC.hutchinson_diag(loss_fn, params, micro, key)
-                    if pc.uses_hutchinson else PC.grad_stat(grads))
-            if pc.rule == "linear" and not pc.uses_hutchinson:
-                stat = jax.tree.map(jnp.abs, grads)
-            pstate = PC.update(pc, pstate, stat)
-        params, mom = _apply_update(params, mom, grads, pstate, spec)
+        with jax.named_scope("model"):
+            if semi:
+                loss, grads = obj_grad(params, micro, key)
+            else:
+                loss, grads = grad_fn(params, micro)
+        with jax.named_scope("local_step"):
+            grads = _clip(grads, cl.grad_clip)
+            if cl.scaling == "local" and pc.kind != "identity":
+                stat = (PC.hutchinson_diag(loss_fn, params, micro, key)
+                        if pc.uses_hutchinson else PC.grad_stat(grads))
+                if pc.rule == "linear" and not pc.uses_hutchinson:
+                    stat = jax.tree.map(jnp.abs, grads)
+                pstate = PC.update(pc, pstate, stat)
+            params, mom = _apply_update(params, mom, grads, pstate, spec)
         return params, mom, pstate, loss, grads
 
     global_d = cl.scaling == "global"
@@ -649,11 +651,12 @@ def _client_loop(loss_fn, grad_fn, spec: EngineSpec, shard_plan=None,
                     lambda a, b: jnp.where(
                         active.reshape((M,) + (1,) * (a.ndim - 1)), a, b),
                     n, o)
-                new_params = sel(new_params, params_m)
-                new_mom = sel(new_mom, mom_m)
-                grads = sel(grads, grads_c)
-                if not global_d:
-                    new_pstate = sel(new_pstate, pstate)
+                with jax.named_scope("local_step"):
+                    new_params = sel(new_params, params_m)
+                    new_mom = sel(new_mom, mom_m)
+                    grads = sel(grads, grads_c)
+                    if not global_d:
+                        new_pstate = sel(new_pstate, pstate)
             return (new_params, new_mom, new_pstate, grads), losses
 
         grads0 = jax.tree.map(jnp.zeros_like, params_m)
@@ -792,37 +795,42 @@ def _fused_run(loss_fn, grad_fn, spec: EngineSpec, tree_run, shard_plan=None,
             else:
                 micro_m, ks = xs
             params_tree = unflat_m(carry["p"])
-            if semi:
-                losses, grads = jax.vmap(obj_grad)(params_tree, micro_m, ks)
-            else:
-                losses, grads = jax.vmap(grad_fn)(params_tree, micro_m)
-            if cl.grad_clip:
-                # tree-level clip, exactly as the tree path: the CLIPPED
-                # grads are what the carry freezes for the sync-time D stat
-                grads = jax.vmap(lambda gt: _clip(gt, cl.grad_clip))(grads)
-            G = flat_m(grads)
-            hstat = None
-            if local and pc.uses_hutchinson:
-                stats = jax.vmap(lambda p_, mc, k_: PC.hutchinson_diag(
-                    loss_fn, p_, mc, k_))(params_tree, micro_m, ks)
-                hstat = flat_m(stats)
-            p_new, m_new, d_new = fused_step(
-                carry["p"], carry["m"], G, carry.get("d"), hstat,
-                carry.get("t"), None, gamma=cl.lr, beta1=cl.momentum,
-                weight_decay=cl.weight_decay, alpha=pc.alpha, beta2=pc.beta2,
-                kind=pc.kind, clip=pc.clip, schedule=pc.schedule,
-                update_d=local)
-            new = dict(carry)
-            new["p"], new["m"], new["g"] = p_new, m_new, G
-            if local:
-                new["d"] = d_new
-                new["t"] = carry["t"] + 1
-            if masked:
-                aw = active[:, None]
-                for k2 in ("p", "m", "g") + (("d",) if local else ()):
-                    new[k2] = jnp.where(aw, new[k2], carry[k2])
+            with jax.named_scope("model"):
+                if semi:
+                    losses, grads = jax.vmap(obj_grad)(params_tree, micro_m,
+                                                       ks)
+                else:
+                    losses, grads = jax.vmap(grad_fn)(params_tree, micro_m)
+            with jax.named_scope("local_step"):
+                if cl.grad_clip:
+                    # tree-level clip, exactly as the tree path: the CLIPPED
+                    # grads are what the carry freezes for the sync-time D
+                    # stat
+                    grads = jax.vmap(lambda gt: _clip(gt, cl.grad_clip))(
+                        grads)
+                G = flat_m(grads)
+                hstat = None
+                if local and pc.uses_hutchinson:
+                    stats = jax.vmap(lambda p_, mc, k_: PC.hutchinson_diag(
+                        loss_fn, p_, mc, k_))(params_tree, micro_m, ks)
+                    hstat = flat_m(stats)
+                p_new, m_new, d_new = fused_step(
+                    carry["p"], carry["m"], G, carry.get("d"), hstat,
+                    carry.get("t"), None, gamma=cl.lr, beta1=cl.momentum,
+                    weight_decay=cl.weight_decay, alpha=pc.alpha,
+                    beta2=pc.beta2, kind=pc.kind, clip=pc.clip,
+                    schedule=pc.schedule, update_d=local)
+                new = dict(carry)
+                new["p"], new["m"], new["g"] = p_new, m_new, G
                 if local:
-                    new["t"] = jnp.where(active, new["t"], carry["t"])
+                    new["d"] = d_new
+                    new["t"] = carry["t"] + 1
+                if masked:
+                    aw = active[:, None]
+                    for k2 in ("p", "m", "g") + (("d",) if local else ()):
+                        new[k2] = jnp.where(aw, new[k2], carry[k2])
+                    if local:
+                        new["t"] = jnp.where(active, new["t"], carry["t"])
             return new, losses
 
         xs = (micro, keys, jnp.arange(H, dtype=jnp.int32)) if masked \
@@ -1261,196 +1269,208 @@ def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
             params_m, mom_m, pstate, last_grads, losses = client_run(
                 state["params"], mom0, state["precond"], micro, keys)
 
-        drift_pre_sync = client_drift(params_m)
+        with jax.named_scope("sync"):
+            drift_pre_sync = client_drift(params_m)
 
-        # ---- Controller observations: raw per-client delta statistics ------
-        ctrl_obs = None
-        if ctrl.enabled:
-            # synced leaves only: personal deltas are client-resident and
-            # must not enter the controller's cross-client noise estimate
-            x_ref0 = strip(jax.tree.map(lambda p: p[0], state["params"]))
-            d_m = jax.tree.map(lambda p, x: p - x[None], strip(params_m),
-                               x_ref0)
-            d2_pc = sum(jnp.sum(jnp.reshape(d * d, (M, -1)), axis=1)
-                        for d in jax.tree.leaves(d_m))           # (M,)
-            dbar_sq = sum(jnp.vdot(b, b).real for b in jax.tree.leaves(
-                jax.tree.map(lambda d: d.mean(axis=0), d_m)))
-            ctrl_obs = {"delta_sq_mean": d2_pc.mean(),
-                        "delta_sq_avg": dbar_sq,
-                        "payload_sq": jnp.float32(0.0),
-                        "resid_sq": jnp.float32(0.0)}
+            # ---- Controller observations: raw per-client delta statistics
+            ctrl_obs = None
+            if ctrl.enabled:
+                # synced leaves only: personal deltas are client-resident and
+                # must not enter the controller's cross-client noise estimate
+                x_ref0 = strip(jax.tree.map(lambda p: p[0], state["params"]))
+                d_m = jax.tree.map(lambda p, x: p - x[None], strip(params_m),
+                                   x_ref0)
+                d2_pc = sum(jnp.sum(jnp.reshape(d * d, (M, -1)), axis=1)
+                            for d in jax.tree.leaves(d_m))           # (M,)
+                dbar_sq = sum(jnp.vdot(b, b).real for b in jax.tree.leaves(
+                    jax.tree.map(lambda d: d.mean(axis=0), d_m)))
+                ctrl_obs = {"delta_sq_mean": d2_pc.mean(),
+                            "delta_sq_avg": dbar_sq,
+                            "payload_sq": jnp.float32(0.0),
+                            "resid_sq": jnp.float32(0.0)}
 
-        # ---- SyncStrategy: the only cross-client traffic per round ---------
-        avg = make_sync(sy, key, M)
-        comp, asy = sy.compression, sy.asynchrony
-        new_ef = delta_avg = comp_err = new_buffer = staleness = None
-        # every tree below is the SYNCED view: ``strip`` (identity for the
-        # empty personalization mask) None-strips the client-resident leaves,
-        # so no average / delta / compression / buffer op ever touches them
-        # (DESIGN.md §12) — ``params_avg`` is a synced-leaf tree recombined
-        # with the untouched personal leaves at broadcast-back
-        if comp.is_identity() and asy.is_identity():
-            # bit-for-bit the uncompressed synchronous program (DESIGN.md
-            # §4/§5 contract) — no delta reconstruction, no residual/buffer
-            # state
-            params_avg = jax.tree.map(avg, strip(params_m))
-        else:
-            # delta form: Δ_m = x_{m,H} − x_t (clients start each round at
-            # the common broadcast point, so x_t = params[0])
-            x_ref = strip(jax.tree.map(lambda p: p[0], state["params"]))
-            u_m = jax.tree.map(lambda p, x: p - x[None], strip(params_m),
-                               x_ref)
-            if comp.is_identity():
-                c_m = u_m
+            # ---- SyncStrategy: the only cross-client traffic per round -----
+            avg = make_sync(sy, key, M)
+            comp, asy = sy.compression, sy.asynchrony
+            new_ef = delta_avg = comp_err = new_buffer = staleness = None
+            # every tree below is the SYNCED view: ``strip`` (identity for
+            # the empty personalization mask) None-strips the client-resident
+            # leaves, so no average / delta / compression / buffer op ever
+            # touches them (DESIGN.md §12) — ``params_avg`` is a synced-leaf
+            # tree recombined with the untouched personal leaves at
+            # broadcast-back
+            if comp.is_identity() and asy.is_identity():
+                # bit-for-bit the uncompressed synchronous program (DESIGN.md
+                # §4/§5 contract) — no delta reconstruction, no
+                # residual/buffer state
+                params_avg = jax.tree.map(avg, strip(params_m))
             else:
-                if comp.error_feedback:
-                    u_m = jax.tree.map(jnp.add, u_m, state["ef"])
-                k_dyn = cstate["k"] if (ctrl.enabled
-                                        and comp.op in ("topk", "randk")) \
-                    else None
-                c_m = compress_tree(comp, u_m, key, k_frac=k_dyn)
-                if comp.error_feedback:
-                    new_ef = jax.tree.map(jnp.subtract, u_m, c_m)
-                comp_err = sum(jnp.vdot(u - c, u - c).real for u, c in zip(
-                    jax.tree.leaves(u_m), jax.tree.leaves(c_m)))
-                if ctrl_obs is not None:
-                    # the compressor's actual input/residual energies feed
-                    # the controller's EF-residual-norm guard
-                    ctrl_obs["payload_sq"] = sum(
-                        jnp.vdot(u, u).real for u in jax.tree.leaves(u_m))
-                    ctrl_obs["resid_sq"] = comp_err
-            delta_avg = jax.tree.map(avg, c_m)
-            if ctrl.enabled and ctrl.buffer_max > 0:
-                # controller-skipped stragglers (h_m = 0) contributed Δ = 0:
-                # rescale the mean to the reporting subset, exactly the
-                # 1/n_part weighting of FedAvg client sampling
-                n_act = jnp.maximum(
-                    jnp.sum((h_m_dyn > 0).astype(jnp.float32)), 1.0)
-                delta_avg = jax.tree.map(
-                    lambda d: d * (M / n_act).astype(d.dtype), delta_avg)
-            if not asy.is_identity():
-                # FedBuff-style staleness buffer (DESIGN.md §5): enqueue the
-                # fresh aggregated delta, apply the staleness-weighted
-                # combination of the FIFO
-                b_eff = cstate["b_eff"] if (ctrl.enabled
-                                            and ctrl.buffer_max > 0) else None
-                w = staleness_weights(asy, state["round"], b_eff=b_eff)
-                new_buffer = jax.tree.map(
-                    lambda b, d: jnp.concatenate(
-                        [d[None].astype(b.dtype), b[:-1]], axis=0),
-                    state["buffer"], delta_avg)
-                delta_avg = jax.tree.map(
-                    lambda b: jnp.tensordot(w.astype(b.dtype), b, axes=1),
-                    new_buffer)
-                staleness = jnp.sum(
-                    w * jnp.arange(asy.buffer_rounds, dtype=jnp.float32))
-            params_avg = jax.tree.map(
-                lambda x, d: x + d.astype(x.dtype), x_ref, delta_avg)
-
-        if sv.kind == "average":
-            # personal leaves keep each client's own value (no broadcast)
-            params_m = _broadcast_back(params_m, params_avg)
-            params_avg = jax.tree.map(lambda x: x[0], params_m)
-            if sy.average_momentum:
-                mom_m = _merge_personal(
-                    strip(mom_m), mom_m,
-                    lambda s, m: jnp.broadcast_to(
-                        avg(s)[None], m.shape).astype(m.dtype))
-
-        # ---- D update at sync (global scaling; Algorithm 1 line 4) ---------
-        if cl.scaling == "global" and pc.kind != "identity":
-            g_last = last_grads  # (M, ...) — grads of the sync step
-            if cl.stat_source == "avg_grad":
-                g_avg = jax.tree.map(avg, g_last)  # participation+dtype apply
-                if pc.uses_hutchinson:
-                    sync_micro = jax.tree.map(lambda x: x[-1, 0], micro)
-                    stat = PC.hutchinson_diag(loss_fn, params_avg, sync_micro,
-                                              jax.random.fold_in(key, 7))
-                elif pc.rule == "linear":
-                    stat = jax.tree.map(jnp.abs, g_avg)
+                # delta form: Δ_m = x_{m,H} − x_t (clients start each round
+                # at the common broadcast point, so x_t = params[0])
+                x_ref = strip(jax.tree.map(lambda p: p[0], state["params"]))
+                u_m = jax.tree.map(lambda p, x: p - x[None], strip(params_m),
+                                   x_ref)
+                if comp.is_identity():
+                    c_m = u_m
                 else:
-                    stat = PC.grad_stat(g_avg)
-            else:  # avg_local
-                if pc.uses_hutchinson:
-                    sync_micro = jax.tree.map(lambda x: x[-1], micro)  # (M,..)
-                    hk = jax.random.split(jax.random.fold_in(key, 7), M)
-                    stats = jax.vmap(lambda p, mc, k: PC.hutchinson_diag(
-                        loss_fn, p, mc, k))(params_m, sync_micro, hk)
-                elif pc.rule == "linear":
-                    stats = jax.tree.map(jnp.abs, g_last)
-                else:
-                    stats = PC.grad_stat(g_last)
-                stat = jax.tree.map(lambda s: s.mean(axis=0), stats)
-            pstate = PC.update(pc, pstate, stat)
+                    if comp.error_feedback:
+                        u_m = jax.tree.map(jnp.add, u_m, state["ef"])
+                    k_dyn = cstate["k"] if (ctrl.enabled
+                                            and comp.op in ("topk", "randk")) \
+                        else None
+                    c_m = compress_tree(comp, u_m, key, k_frac=k_dyn)
+                    if comp.error_feedback:
+                        new_ef = jax.tree.map(jnp.subtract, u_m, c_m)
+                    comp_err = sum(jnp.vdot(u - c, u - c).real for u, c in zip(
+                        jax.tree.leaves(u_m), jax.tree.leaves(c_m)))
+                    if ctrl_obs is not None:
+                        # the compressor's actual input/residual energies feed
+                        # the controller's EF-residual-norm guard
+                        ctrl_obs["payload_sq"] = sum(
+                            jnp.vdot(u, u).real for u in jax.tree.leaves(u_m))
+                        ctrl_obs["resid_sq"] = comp_err
+                delta_avg = jax.tree.map(avg, c_m)
+                if ctrl.enabled and ctrl.buffer_max > 0:
+                    # controller-skipped stragglers (h_m = 0) contributed
+                    # Δ = 0: rescale the mean to the reporting subset, exactly
+                    # the 1/n_part weighting of FedAvg client sampling
+                    n_act = jnp.maximum(
+                        jnp.sum((h_m_dyn > 0).astype(jnp.float32)), 1.0)
+                    delta_avg = jax.tree.map(
+                        lambda d: d * (M / n_act).astype(d.dtype), delta_avg)
+                if not asy.is_identity():
+                    # FedBuff-style staleness buffer (DESIGN.md §5): enqueue
+                    # the fresh aggregated delta, apply the staleness-weighted
+                    # combination of the FIFO
+                    b_eff = cstate["b_eff"] if (
+                        ctrl.enabled and ctrl.buffer_max > 0) else None
+                    w = staleness_weights(asy, state["round"], b_eff=b_eff)
+                    new_buffer = jax.tree.map(
+                        lambda b, d: jnp.concatenate(
+                            [d[None].astype(b.dtype), b[:-1]], axis=0),
+                        state["buffer"], delta_avg)
+                    delta_avg = jax.tree.map(
+                        lambda b: jnp.tensordot(w.astype(b.dtype), b, axes=1),
+                        new_buffer)
+                    staleness = jnp.sum(
+                        w * jnp.arange(asy.buffer_rounds, dtype=jnp.float32))
+                params_avg = jax.tree.map(
+                    lambda x, d: x + d.astype(x.dtype), x_ref, delta_avg)
 
-        if h_m_dyn is not None or _needs_masking(cl, H, M):
-            # heterogeneous H_m: steps past a client's budget froze its state;
-            # average only the executed steps, and report each client's loss
-            # at ITS final step H_m−1, not the global step H−1. (For a
-            # controller-skipped client, H_m = 0, its rows drop from the mean
-            # and the clamped index reports its frozen round-start loss.)
-            h_m = h_m_dyn if h_m_dyn is not None \
-                else jnp.asarray(cl.local_steps, jnp.int32)
-            act = jnp.arange(H, dtype=jnp.int32)[:, None] < h_m[None, :]
-            loss_mean = jnp.sum(losses * act) / jnp.maximum(jnp.sum(act), 1)
-            loss_per_client = jnp.take_along_axis(
-                losses, jnp.maximum(h_m - 1, 0)[None, :], axis=0)[0]
-        else:
-            loss_mean = losses.mean()
-            loss_per_client = losses[-1]
-        metrics = {
-            "loss": loss_mean,
-            "loss_per_client": loss_per_client,
-            "client_drift": drift_pre_sync,
-        }
-        if comp_err is not None:
-            metrics["compression_err"] = comp_err  # Σ‖u_m − C(u_m)‖²
-        if staleness is not None:
-            metrics["staleness"] = staleness  # E_w[τ] of the applied delta
-        if ctrl.enabled:
-            # realized knobs of THIS round + the raw observations, so a
-            # numpy replay (tests/_reference_controller.py) can reproduce
-            # the whole trajectory from logs alone
-            metrics["ctrl_h_m"] = h_m_dyn
-            metrics["ctrl_h_t"] = cstate["h_t"]
-            metrics["ctrl_k"] = cstate["k"]
-            metrics["ctrl_b_eff"] = cstate["b_eff"] if ctrl.buffer_max > 0 \
-                else jnp.int32(0)  # 0 = depth not managed by the controller
-            metrics["delta_sq_mean"] = ctrl_obs["delta_sq_mean"]
-            metrics["delta_sq_avg"] = ctrl_obs["delta_sq_avg"]
-            metrics["payload_sq"] = ctrl_obs["payload_sq"]
+            if sv.kind == "average":
+                # personal leaves keep each client's own value (no broadcast)
+                params_m = _broadcast_back(params_m, params_avg)
+                params_avg = jax.tree.map(lambda x: x[0], params_m)
+                if sy.average_momentum:
+                    mom_m = _merge_personal(
+                        strip(mom_m), mom_m,
+                        lambda s, m: jnp.broadcast_to(
+                            avg(s)[None], m.shape).astype(m.dtype))
 
-        # ---- ServerUpdate ---------------------------------------------------
-        new_state = {"round": state["round"] + 1, "precond": pstate}
-        if new_ef is not None:
-            new_state["ef"] = new_ef
-        if new_buffer is not None:
-            new_state["buffer"] = new_buffer
-        if ctrl.enabled:
-            # roll the knobs forward for the NEXT round (pure, jit-traced;
-            # checkpointing the state pytree checkpoints the controller)
-            new_cstate, _ = CTRL.controller_step(ctrl, cstate, ctrl_obs)
-            new_state["ctrl"] = new_cstate
-            metrics["ctrl_gns_ema"] = new_cstate["gns_ema"]
-        if sv.kind == "adaptive":
-            x_prev = strip(jax.tree.map(lambda p: p[0], state["params"]))
-            if delta_avg is not None:
-                # compressed path: Δ is exactly the averaged compressed delta
-                # (params_avg = x_prev + Δ would re-add/re-subtract x_prev)
-                delta = jax.tree.map(
-                    lambda d, x: d.astype(x.dtype), delta_avg, x_prev)
+        with jax.named_scope("server"):
+            # ---- D update at sync (global scaling; Algorithm 1 line 4) -----
+            if cl.scaling == "global" and pc.kind != "identity":
+                g_last = last_grads  # (M, ...) — grads of the sync step
+                if cl.stat_source == "avg_grad":
+                    # participation+dtype apply
+                    g_avg = jax.tree.map(avg, g_last)
+                    if pc.uses_hutchinson:
+                        sync_micro = jax.tree.map(lambda x: x[-1, 0], micro)
+                        stat = PC.hutchinson_diag(
+                            loss_fn, params_avg, sync_micro,
+                            jax.random.fold_in(key, 7))
+                    elif pc.rule == "linear":
+                        stat = jax.tree.map(jnp.abs, g_avg)
+                    else:
+                        stat = PC.grad_stat(g_avg)
+                else:  # avg_local
+                    if pc.uses_hutchinson:
+                        # (M, ...)
+                        sync_micro = jax.tree.map(lambda x: x[-1], micro)
+                        hk = jax.random.split(jax.random.fold_in(key, 7), M)
+                        stats = jax.vmap(lambda p, mc, k: PC.hutchinson_diag(
+                            loss_fn, p, mc, k))(params_m, sync_micro, hk)
+                    elif pc.rule == "linear":
+                        stats = jax.tree.map(jnp.abs, g_last)
+                    else:
+                        stats = PC.grad_stat(g_last)
+                    stat = jax.tree.map(lambda s: s.mean(axis=0), stats)
+                pstate = PC.update(pc, pstate, stat)
+
+            if h_m_dyn is not None or _needs_masking(cl, H, M):
+                # heterogeneous H_m: steps past a client's budget froze its
+                # state; average only the executed steps, and report each
+                # client's loss at ITS final step H_m−1, not the global step
+                # H−1. (For a controller-skipped client, H_m = 0, its rows
+                # drop from the mean and the clamped index reports its frozen
+                # round-start loss.)
+                h_m = h_m_dyn if h_m_dyn is not None \
+                    else jnp.asarray(cl.local_steps, jnp.int32)
+                act = jnp.arange(H, dtype=jnp.int32)[:, None] < h_m[None, :]
+                loss_mean = jnp.sum(losses * act) / jnp.maximum(
+                    jnp.sum(act), 1)
+                loss_per_client = jnp.take_along_axis(
+                    losses, jnp.maximum(h_m - 1, 0)[None, :], axis=0)[0]
             else:
-                delta = jax.tree.map(
-                    lambda a, x: a.astype(x.dtype) - x, params_avg, x_prev)
-            x_new, server = _adaptive_server_update(sv, state["server"],
-                                                    x_prev, delta)
-            params_m = _broadcast_back(params_m, x_new)
-            new_state["server"] = server
-            metrics["step_norm"] = jnp.sqrt(sum(
-                jnp.vdot(a - b, a - b).real for a, b in zip(
-                    jax.tree.leaves(x_new), jax.tree.leaves(x_prev))))
-        new_state["params"] = params_m
-        new_state["mom"] = mom_m
+                loss_mean = losses.mean()
+                loss_per_client = losses[-1]
+            metrics = {
+                "loss": loss_mean,
+                "loss_per_client": loss_per_client,
+                "client_drift": drift_pre_sync,
+            }
+            if comp_err is not None:
+                # Σ‖u_m − C(u_m)‖²
+                metrics["compression_err"] = comp_err
+            if staleness is not None:
+                # E_w[τ] of the applied delta
+                metrics["staleness"] = staleness
+            if ctrl.enabled:
+                # realized knobs of THIS round + the raw observations, so a
+                # numpy replay (tests/_reference_controller.py) can reproduce
+                # the whole trajectory from logs alone
+                metrics["ctrl_h_m"] = h_m_dyn
+                metrics["ctrl_h_t"] = cstate["h_t"]
+                metrics["ctrl_k"] = cstate["k"]
+                # 0 = depth not managed by the controller
+                metrics["ctrl_b_eff"] = cstate["b_eff"] \
+                    if ctrl.buffer_max > 0 else jnp.int32(0)
+                metrics["delta_sq_mean"] = ctrl_obs["delta_sq_mean"]
+                metrics["delta_sq_avg"] = ctrl_obs["delta_sq_avg"]
+                metrics["payload_sq"] = ctrl_obs["payload_sq"]
+
+            # ---- ServerUpdate -----------------------------------------------
+            new_state = {"round": state["round"] + 1, "precond": pstate}
+            if new_ef is not None:
+                new_state["ef"] = new_ef
+            if new_buffer is not None:
+                new_state["buffer"] = new_buffer
+            if ctrl.enabled:
+                # roll the knobs forward for the NEXT round (pure, jit-traced;
+                # checkpointing the state pytree checkpoints the controller)
+                new_cstate, _ = CTRL.controller_step(ctrl, cstate, ctrl_obs)
+                new_state["ctrl"] = new_cstate
+                metrics["ctrl_gns_ema"] = new_cstate["gns_ema"]
+            if sv.kind == "adaptive":
+                x_prev = strip(jax.tree.map(lambda p: p[0], state["params"]))
+                if delta_avg is not None:
+                    # compressed path: Δ is exactly the averaged compressed
+                    # delta (params_avg = x_prev + Δ would re-add/re-subtract
+                    # x_prev)
+                    delta = jax.tree.map(
+                        lambda d, x: d.astype(x.dtype), delta_avg, x_prev)
+                else:
+                    delta = jax.tree.map(
+                        lambda a, x: a.astype(x.dtype) - x, params_avg, x_prev)
+                x_new, server = _adaptive_server_update(sv, state["server"],
+                                                        x_prev, delta)
+                params_m = _broadcast_back(params_m, x_new)
+                new_state["server"] = server
+                metrics["step_norm"] = jnp.sqrt(sum(
+                    jnp.vdot(a - b, a - b).real for a, b in zip(
+                        jax.tree.leaves(x_new), jax.tree.leaves(x_prev))))
+            new_state["params"] = params_m
+            new_state["mom"] = mom_m
         return new_state, metrics
 
     return round_step

@@ -56,6 +56,9 @@ from repro.models import ModelCallConfig, build
 from repro.utils.compile_cache import enable_compile_cache
 
 
+PROFILE_ROUNDS = 3      # rounds traced by --profile-dir
+
+
 def _parser():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -165,6 +168,11 @@ def _parser():
                          "per-shard via shard_map on model-/FSDP-sharded "
                          "plans; the single-host path uses the unsharded "
                          "flat view")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a profiler trace of the PROFILE_ROUNDS rounds "
+                         "after the compile round to this directory: each "
+                         "round a step 'round', with the host spans "
+                         "make_batch, put_batch, dispatch, read_loss")
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--log", default="")
@@ -365,64 +373,82 @@ def main(argv=None):
     tokens_round = M * args.h_local * args.batch * args.seq
     compiled = None
     t0 = time.time()
+    profiling = False
     with mesh if mesh is not None else contextlib.nullcontext():
         for r in range(start_round, args.rounds):
-            nb = loader.round_batch(r % args.data_rounds if args.data_rounds
-                                    else r, args.h_local, args.seq)
-            if cfg.family in ("audio", "vlm"):
-                nb = _wrap_modal(cfg, nb, args.seed, r)
-            batch = put_batch(nb)
-            call_args = step_args(state, batch, r)
-            if compiled is None:
-                compiled, info = _compile(jitted, call_args)
-                log.setup.update(info)
-                print(f"[train] round step compiled in "
-                      f"{info['compile_s']:.1f}s: "
-                      + "".join(f"{k} {info[k] / 1e9:.3f} GB, " for k in
-                                ("argument_bytes", "temp_bytes", "peak_bytes")
-                                if k in info)
-                      + f"{info['pallas_calls']} Pallas calls", flush=True)
-            tw = time.perf_counter()
-            state, metrics = compiled(*call_args)
-            loss = float(metrics["loss"])          # blocks on the round
-            wall = time.perf_counter() - tw
-            drift = float(metrics["client_drift"])
-            rec = {"round": r, "loss": loss, "drift": drift}
-            extra = ""
-            if "step_norm" in metrics:
-                rec["step_norm"] = float(metrics["step_norm"])
-                extra = f" step {rec['step_norm']:.3e}"
-            if "compression_err" in metrics:
-                rec["compression_err"] = float(metrics["compression_err"])
-            if "staleness" in metrics:
-                rec["staleness"] = float(metrics["staleness"])
-            if "ctrl_h_m" in metrics:
-                # realized knob trajectory (DESIGN.md §10). Per-round
-                # sim_round_time (not a cumulative) so a resumed run logs
-                # bitwise-identical rounds; consumers sum it themselves.
-                h_real = [int(h) for h in np.asarray(metrics["ctrl_h_m"])]
-                b_real = int(metrics["ctrl_b_eff"])
-                rec["ctrl_h_m"] = h_real
-                rec["ctrl_h_t"] = int(metrics["ctrl_h_t"])
-                rec["ctrl_k"] = round(float(metrics["ctrl_k"]), 6)
-                rec["ctrl_b_eff"] = b_real
-                rec["ctrl_gns_ema"] = round(float(metrics["ctrl_gns_ema"]), 6)
-                extra += f" H_t {rec['ctrl_h_t']}"
-                rec["sim_round_time"] = round(federated.simulated_round_time(
-                    step_times, h_real,
-                    barrier="async" if args.async_buffer else "sync",
-                    buffer_rounds=b_real or args.async_buffer), 4)
-            else:
-                rec["sim_time"] = round((r + 1) * sim_t, 4)  # simulated clock
-            # measurements — the only non-deterministic log fields (§9)
-            rec["wall_s"] = round(wall, 4)
-            rec["tokens_per_s"] = round(tokens_round / wall, 1)
-            log.append(rec)
-            print(f"[train] round {r:4d} loss {loss:.4f} drift {drift:.3e}"
-                  f"{extra} {wall:.3f}s/round {rec['tokens_per_s']:.0f} "
-                  f"tok/s ({time.time()-t0:.1f}s)", flush=True)
-            if args.ckpt and (r + 1) % args.ckpt_every == 0:
-                ckpt_lib.save(args.ckpt, r + 1, state)
+            if args.profile_dir and r == start_round + 1:
+                jax.profiler.start_trace(args.profile_dir)
+                profiling = True
+            with jax.profiler.StepTraceAnnotation("round", step_num=r):
+                with jax.profiler.TraceAnnotation("make_batch"):
+                    nb = loader.round_batch(
+                        r % args.data_rounds if args.data_rounds else r,
+                        args.h_local, args.seq)
+                    if cfg.family in ("audio", "vlm"):
+                        nb = _wrap_modal(cfg, nb, args.seed, r)
+                with jax.profiler.TraceAnnotation("put_batch"):
+                    batch = put_batch(nb)
+                call_args = step_args(state, batch, r)
+                if compiled is None:
+                    compiled, info = _compile(jitted, call_args)
+                    log.setup.update(info)
+                    print(f"[train] round step compiled in "
+                          f"{info['compile_s']:.1f}s: "
+                          + "".join(f"{k} {info[k] / 1e9:.3f} GB, "
+                                    for k in ("argument_bytes", "temp_bytes",
+                                              "peak_bytes") if k in info)
+                          + f"{info['pallas_calls']} Pallas calls", flush=True)
+                tw = time.perf_counter()
+                with jax.profiler.TraceAnnotation("dispatch"):
+                    state, metrics = compiled(*call_args)
+                with jax.profiler.TraceAnnotation("read_loss"):
+                    loss = float(metrics["loss"])      # blocks on the round
+                wall = time.perf_counter() - tw
+                drift = float(metrics["client_drift"])
+                rec = {"round": r, "loss": loss, "drift": drift}
+                extra = ""
+                if "step_norm" in metrics:
+                    rec["step_norm"] = float(metrics["step_norm"])
+                    extra = f" step {rec['step_norm']:.3e}"
+                if "compression_err" in metrics:
+                    rec["compression_err"] = float(metrics["compression_err"])
+                if "staleness" in metrics:
+                    rec["staleness"] = float(metrics["staleness"])
+                if "ctrl_h_m" in metrics:
+                    # realized knob trajectory (DESIGN.md §10). Per-round
+                    # sim_round_time (not a cumulative) so a resumed run logs
+                    # bitwise-identical rounds; consumers sum it themselves.
+                    h_real = [int(h) for h in np.asarray(metrics["ctrl_h_m"])]
+                    b_real = int(metrics["ctrl_b_eff"])
+                    rec["ctrl_h_m"] = h_real
+                    rec["ctrl_h_t"] = int(metrics["ctrl_h_t"])
+                    rec["ctrl_k"] = round(float(metrics["ctrl_k"]), 6)
+                    rec["ctrl_b_eff"] = b_real
+                    rec["ctrl_gns_ema"] = round(
+                        float(metrics["ctrl_gns_ema"]), 6)
+                    extra += f" H_t {rec['ctrl_h_t']}"
+                    rec["sim_round_time"] = round(
+                        federated.simulated_round_time(
+                            step_times, h_real,
+                            barrier="async" if args.async_buffer else "sync",
+                            buffer_rounds=b_real or args.async_buffer), 4)
+                else:
+                    # simulated clock
+                    rec["sim_time"] = round((r + 1) * sim_t, 4)
+                # measurements — the only non-deterministic log fields (§9)
+                rec["wall_s"] = round(wall, 4)
+                rec["tokens_per_s"] = round(tokens_round / wall, 1)
+                log.append(rec)
+                print(f"[train] round {r:4d} loss {loss:.4f} drift {drift:.3e}"
+                      f"{extra} {wall:.3f}s/round {rec['tokens_per_s']:.0f} "
+                      f"tok/s ({time.time()-t0:.1f}s)", flush=True)
+                if args.ckpt and (r + 1) % args.ckpt_every == 0:
+                    ckpt_lib.save(args.ckpt, r + 1, state)
+            if profiling and r == start_round + PROFILE_ROUNDS:
+                jax.profiler.stop_trace()
+                profiling = False
+    if profiling:
+        jax.profiler.stop_trace()
     log.setup["peak_bytes_in_use"] = [
         (d.memory_stats() or {}).get("peak_bytes_in_use")
         for d in jax.local_devices()]

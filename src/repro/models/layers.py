@@ -235,6 +235,7 @@ class AttnCall:
     moe_shard: object = None  # sharding-constraint hook for MoE buffers
 
 
+@jax.named_scope("attention")
 def attention(p, cfg: ModelConfig, x, positions, call: AttnCall, dtype):
     """Full self-attention over x (B,S,d) at integer positions (S,).
 
@@ -379,6 +380,7 @@ def embed(p, tokens, dtype):
     return p["table"].astype(dtype)[tokens]
 
 
+@jax.named_scope("lm_head")
 def unembed(p, x, cfg: ModelConfig, dtype):
     if cfg.tie_embeddings:
         logits = x.astype(dtype) @ p["table"].astype(dtype).T
@@ -388,6 +390,7 @@ def unembed(p, x, cfg: ModelConfig, dtype):
     return logits
 
 
+@jax.named_scope("lm_head")
 def cross_entropy(logits, labels, vocab_size):
     """Mean CE over positions; labels < 0 are masked out; padded vocab masked."""
     V = logits.shape[-1]

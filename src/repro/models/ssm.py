@@ -74,6 +74,7 @@ def _segsum_exp(cum):
     return jnp.exp(jnp.where(mask, diff, -1e30)) * mask
 
 
+@jax.named_scope("ssd_scan")
 def ssd_chunked(xh, dt, A, Bm, Cm, chunk, h0=None):
     """Chunked SSD scan.
 

@@ -143,6 +143,28 @@ def test_layers_cuts_depth_and_reports_setup():
     assert "fused_kernel_fallback" not in s
 
 
+def test_profile_dir_traces_the_rounds_after_compile(tmp_path):
+    """--profile-dir writes one trace of the PROFILE_ROUNDS rounds after the
+    compile round: each a step 'round' holding the four host spans."""
+    import glob
+    import jax
+    from repro.launch import train as train_mod
+    logdir = str(tmp_path / "prof")
+    n = train_mod.PROFILE_ROUNDS
+    log = train_mod.main(BASE + ["--rounds", str(n + 2), "--layers", "1",
+                                 "--profile-dir", logdir])
+    assert len(log) == n + 2
+    found = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(found) == 1
+    pd = jax.profiler.ProfileData.from_file(found[0])
+    names = ("round", "make_batch", "put_batch", "dispatch", "read_loss")
+    seen = [e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events if e.name in names]
+    assert {name: seen.count(name) for name in names} == \
+        {name: n for name in names}
+
+
 @pytest.mark.slow
 def test_resume_bitwise_10_rounds(tmp_path):
     """The contract at the issue's full length: train(10) == train(5)+train(5)."""
