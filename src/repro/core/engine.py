@@ -498,19 +498,27 @@ def strip_personal(personal: tuple, tree, is_leaf=None):
     # themselves containers (PartitionSpec tuples in sharding-spec trees)
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree,
                                                          is_leaf=is_leaf)
-    new = []
-    for path, leaf in flat:
-        keys = []
-        for p in path:
-            if isinstance(p, jax.tree_util.DictKey):
-                keys.append(str(p.key))
-            elif isinstance(p, jax.tree_util.SequenceKey):
-                keys.append(str(p.idx))
-            else:
-                keys.append(str(p))
-        s = "/".join(keys)
-        new.append(None if any(pat in s for pat in personal) else leaf)
+    new = [None if _is_personal(personal, path) else leaf
+           for path, leaf in flat]
     return jax.tree_util.tree_unflatten(treedef, new)
+
+
+def _path_str(path) -> str:
+    """'/'-joined keys of a pytree path (the form ``personal`` matches)."""
+    keys = []
+    for p in path:
+        if isinstance(p, jax.tree_util.DictKey):
+            keys.append(str(p.key))
+        elif isinstance(p, jax.tree_util.SequenceKey):
+            keys.append(str(p.idx))
+        else:
+            keys.append(str(p))
+    return "/".join(keys)
+
+
+def _is_personal(personal: tuple, path) -> bool:
+    s = _path_str(path)
+    return any(pat in s for pat in personal)
 
 
 def _merge_personal(stripped, full, merge_fn):
@@ -528,12 +536,14 @@ def average_params(state):
     return jax.tree.map(lambda p: p[0], state["params"])
 
 
+def _leaf_drift(p):
+    mean = p.mean(axis=0, keepdims=True)
+    return jnp.sum((p - mean) ** 2)
+
+
 def client_drift(params_m):
     """(1/M)Σ‖x^m − x̂‖² — the V_t of the analysis (0 right after sync)."""
-    def per_leaf(p):
-        mean = p.mean(axis=0, keepdims=True)
-        return jnp.sum((p - mean) ** 2)
-    return sum(jax.tree.leaves(jax.tree.map(per_leaf, params_m)))
+    return sum(jax.tree.leaves(jax.tree.map(_leaf_drift, params_m)))
 
 
 # --------------------------------------------------------------------------- #
@@ -1118,6 +1128,75 @@ def _broadcast_back(params_m, avg):
                                       ).astype(p.dtype))
 
 
+def _one_pass(spec: EngineSpec, mesh=None) -> bool:
+    """True iff the round's sync is the plain weighted average broadcast back
+    to every client — an averaging server, no ``sync_dtype``, compression
+    or staleness buffer — on one device (no ``mesh``, or a mesh of one):
+    then each client leaf that tiles is synced by the one-pass kernel
+    (``kernels/sync_average.py``). Under GSPMD the kernel would gather the
+    client-sharded state, so a round step built for a mesh of several
+    devices keeps the jnp sync."""
+    sy = spec.sync
+    return ((mesh is None or mesh.size == 1)
+            and spec.server.kind == "average"
+            and not sy.sync_dtype and sy.compression.is_identity()
+            and sy.asynchrony.is_identity())
+
+
+def sync_plan(params, spec: EngineSpec, mesh=None) -> dict:
+    """Which synced leaves the one-pass sync kernel takes and which keep the
+    jnp sync in a round step built for ``mesh`` (as ``build_round_step``),
+    decided by each leaf's shape and dtype (``kops.sync_tiles``).
+
+    ``params`` is one replica's tree (arrays or ShapeDtypeStructs, no M
+    dim). Returns the '/'-joined paths under ``kernel`` and ``jnp``, and
+    ``kernel_bytes`` / ``jnp_bytes``: one replica's bytes of each synced
+    tree (the params, and the momentum when it is averaged). Personal leaves
+    are not synced and appear in neither."""
+    from repro.kernels import ops as kops
+    one_pass = _one_pass(spec, mesh)
+    plan = {"kernel": [], "kernel_bytes": 0, "jnp": [], "jnp_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if _is_personal(spec.sync.personal, path):
+            continue
+        side = "kernel" if one_pass and kops.sync_tiles(
+            (1,) + tuple(leaf.shape), leaf.dtype) else "jnp"
+        plan[side].append(_path_str(path))
+        plan[side + "_bytes"] += math.prod(leaf.shape) \
+            * jnp.dtype(leaf.dtype).itemsize
+    return plan
+
+
+def _sync_one_pass(tree_m, w_part, avg, personal: tuple, drift: bool):
+    """The averaging server's sync of an ``(M, ...)`` client tree: each leaf
+    that tiles is read once by the kernel, which averages it and sums its
+    drift; the other leaves take the jnp average and broadcast-back, and
+    personal leaves keep each client's value. Returns ``(tree,
+    client_drift(tree_m) | None)``."""
+    from repro.kernels import ops as kops
+    flat, treedef = jax.tree_util.tree_flatten_with_path(tree_m)
+    out, drifts = [], []
+    for path, p in flat:
+        if _is_personal(personal, path):
+            out.append(p)
+            drifts.append(_leaf_drift(p) if drift else None)
+        elif kops.sync_tiles(p.shape, p.dtype):
+            # a leaf with a dim between M and its matrix is a layer stack,
+            # which the client loop carries layer-major: the kernel reads it
+            # so and XLA broadcasts the average; other leaves are written
+            # back in place (kernels/sync_average.py)
+            new, d = kops.sync_average(p, w_part, drift=drift,
+                                       layer_major=p.ndim >= 4)
+            out.append(new)
+            drifts.append(d)
+        else:
+            out.append(jnp.broadcast_to(avg(p)[None], p.shape
+                                        ).astype(p.dtype))
+            drifts.append(_leaf_drift(p) if drift else None)
+    return jax.tree_util.tree_unflatten(treedef, out), \
+        (sum(drifts) if drift else None)
+
+
 # --------------------------------------------------------------------------- #
 # ServerUpdate
 # --------------------------------------------------------------------------- #
@@ -1185,7 +1264,7 @@ def _adaptive_server_update(spec: ServerSpec, server, x_prev, delta):
 
 
 def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
-                     objective=None):
+                     objective=None, mesh=None):
     """loss_fn(params, microbatch) -> scalar.
 
     Returns ``round_step(state, batch, key)`` where each batch leaf is
@@ -1209,6 +1288,11 @@ def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
     ``scaling="local"`` (or an identity preconditioner): a GLOBAL D is by
     definition shared state, so combining it with a personalization mask is
     a build-time error rather than a silent wire leak.
+
+    ``mesh`` is the device mesh the step is built for (the launch layer's);
+    ``None`` means one device. On one device the averaging server's plain
+    sync runs as one Pallas pass per leaf that tiles (``sync_plan``); on a
+    mesh of several devices it stays in jnp, for GSPMD to partition.
     """
     grad_fn = jax.value_and_grad(loss_fn)
     cl, sy, sv, pc = spec.client, spec.sync, spec.server, spec.precond
@@ -1220,6 +1304,7 @@ def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
             "personal leaves' gradients over the wire. Use scaling='local' "
             "(per-client D, never synced) or pc kind='identity'.")
     strip = lambda t: strip_personal(personal, t)
+    one_pass = _one_pass(spec, mesh)
     _, client_run = _client_loop(loss_fn, grad_fn, spec, shard_plan,
                                  objective=objective)
     ctrl = spec.controller
@@ -1270,7 +1355,8 @@ def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
                 state["params"], mom0, state["precond"], micro, keys)
 
         with jax.named_scope("sync"):
-            drift_pre_sync = client_drift(params_m)
+            if not one_pass:
+                drift_pre_sync = client_drift(params_m)
 
             # ---- Controller observations: raw per-client delta statistics
             ctrl_obs = None
@@ -1299,7 +1385,18 @@ def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
             # touches them (DESIGN.md §12) — ``params_avg`` is a synced-leaf
             # tree recombined with the untouched personal leaves at
             # broadcast-back
-            if comp.is_identity() and asy.is_identity():
+            if one_pass:
+                # average and drift in one read of each leaf that tiles
+                # (kernels/sync_average.py); at M=2 the average is bitwise
+                # the jnp sync's
+                w_part = participation_weights(sy, key, M)
+                params_m, drift_pre_sync = _sync_one_pass(
+                    params_m, w_part, avg, personal, drift=True)
+                if sy.average_momentum:
+                    mom_m, _ = _sync_one_pass(mom_m, w_part, avg, personal,
+                                              drift=False)
+                params_avg = jax.tree.map(lambda x: x[0], params_m)
+            elif comp.is_identity() and asy.is_identity():
                 # bit-for-bit the uncompressed synchronous program (DESIGN.md
                 # §4/§5 contract) — no delta reconstruction, no
                 # residual/buffer state
@@ -1357,7 +1454,7 @@ def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
                 params_avg = jax.tree.map(
                     lambda x, d: x + d.astype(x.dtype), x_ref, delta_avg)
 
-            if sv.kind == "average":
+            if sv.kind == "average" and not one_pass:
                 # personal leaves keep each client's own value (no broadcast)
                 params_m = _broadcast_back(params_m, params_avg)
                 params_avg = jax.tree.map(lambda x: x[0], params_m)

@@ -14,6 +14,7 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import quantize_update as _qu
 from repro.kernels import scaled_update as _su
 from repro.kernels import ssd_scan as _ssd
+from repro.kernels import sync_average as _sa
 from repro.utils.tree import tree_from_paths
 
 
@@ -83,6 +84,19 @@ def quantize_update(x, u, scale):
     q, dec = _qu.quantize_update_flat(flat(x), flat(u), flat(scale),
                                       interpret=_interpret())
     return q.reshape(shape), dec.reshape(shape).astype(x.dtype)
+
+
+sync_tiles = _sa.tiles
+
+
+def sync_average(x, w, *, drift=False, layer_major=False):
+    """One-pass sync of an ``(M, …)`` fp32 client leaf that ``sync_tiles``:
+    every client slot gets Σ_m w_m·x_m, written over x's buffer, or read
+    layer-major from an ``(M, L, …)`` stack (``sync_average.py``). Returns
+    ``(out, drift_sum)``, ``drift_sum`` = Σ_m ‖x_m − x̄‖² (x̄ the unweighted
+    mean) when ``drift``, else None."""
+    return _sa.sync_average(x, w, drift=drift, layer_major=layer_major,
+                            interpret=_interpret())
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,

@@ -228,7 +228,8 @@ def build_train_step(arch: str, shape: ShapeConfig, mesh, *, mode: str = "auto",
                 state_shape["params"], batch_dims=1).describe()
     round_step = engine.build_round_step(model.loss, spec,
                                          shard_plan=shard_plan,
-                                         objective=client_objective)
+                                         objective=client_objective,
+                                         mesh=mesh)
 
     def step(state, batch):
         # per-round key folded from the carried round counter: restart- and

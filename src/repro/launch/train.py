@@ -306,8 +306,8 @@ def main(argv=None):
     spec, local_steps, step_times = _resolve_spec(args, M)
     model = build(cfg, call)
 
-    wire = engine.bytes_on_wire(spec, jax.eval_shape(model.init,
-                                                     jax.random.PRNGKey(0)))
+    params_one = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    wire = engine.bytes_on_wire(spec, params_one)
     print(f"[train] sync payload/client/round: {wire['total_bytes']/1e6:.3f} "
           f"MB ({wire['compression_x']}x vs uncompressed)", flush=True)
     sim_t = federated.simulated_round_time(
@@ -357,6 +357,15 @@ def main(argv=None):
         step_args = lambda state, batch, r: (
             state, batch, jax.random.fold_in(root, r))
         put_batch = lambda nb: jax.tree.map(jnp.asarray, nb)
+
+    # which synced leaves the one-pass sync kernel takes (one device only)
+    plan = engine.sync_plan(params_one, spec, mesh)
+    log.setup["sync_plan"] = {k: len(v) if isinstance(v, list) else v
+                              for k, v in plan.items()}
+    print(f"[train] sync: one-pass kernel {len(plan['kernel'])} leaves "
+          f"{plan['kernel_bytes'] / 1e6:.3f} MB, jnp {len(plan['jnp'])} "
+          f"leaves {plan['jnp_bytes'] / 1e6:.3f} MB (one replica's tree)",
+          flush=True)
 
     state = engine.init_state(jax.random.PRNGKey(args.seed), model.init, spec,
                               M)

@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and under several test
 workers only the worker given this file may try.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -18,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import decode_step, flash_attention, quantize_update
-from repro.kernels import scaled_update
+from repro.kernels import scaled_update, sync_average
 from repro.models.layers import padded_vocab
 
 CFG = get_config("qwen2-0.5b")
@@ -113,3 +115,28 @@ def test_quantize_update_compiles(one_chip):
     text = _compile_text(quantize_update.quantize_update_flat, one_chip,
                          *[((LEAF,), F32)] * 3)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("leaf", ["ffn-layer-major", "table-in-place"])
+def test_sync_average_compiles(one_chip, leaf):
+    """The one-pass sync at qwen2-0.5b widths (16 layers, M = 2): an FFN
+    stack read layer-major from an (L, M, …) array, as the client loop
+    carries it, and the padded embedding written over its own buffer (the
+    whole input aliased to the output). Neither copies its input."""
+    if leaf == "ffn-layer-major":
+        shape, layer_major = (16, M, CFG.d_model, CFG.d_ff), True
+        view = lambda x: jnp.swapaxes(x, 0, 1)
+    else:
+        shape = (M, padded_vocab(CFG.vocab_size), CFG.d_model)
+        layer_major, view = False, lambda x: x
+    fn = lambda x, w: sync_average.sync_average(view(x), w, drift=True,
+                                                layer_major=layer_major)
+    args = [jax.ShapeDtypeStruct(shape, F32, sharding=one_chip),
+            jax.ShapeDtypeStruct((M,), F32, sharding=one_chip)]
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert " copy(" not in text.split("ENTRY")[1]
+    if not layer_major:
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            == 4 * math.prod(shape)
