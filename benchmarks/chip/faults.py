@@ -5,10 +5,12 @@ built (traced), so the step built inside it carries the fault:
 
 * ``frozen`` — the round step returns the state it was given;
 * ``half_batch`` — the loss leaves out the second half of each sequence's
-  positions and takes the mean over the rest.
-
-(The cells run on one chip, so there is no exchange between chips to
-leave out.)
+  positions and takes the mean over the rest;
+* ``no_exchange`` — the sync's average (``engine.make_sync``) takes client
+  0's copy, so the clients never exchange: the round step of a cell on
+  several chips, one client a chip, leaves out the exchange between them.
+  (On one chip the one-pass sync kernel averages the leaves that tile
+  without it, so the fault is for the cells on several chips.)
 
 The benchmark's runs never enter these; ``control.py`` and the tests do.
 """
@@ -21,6 +23,7 @@ import jax.numpy as jnp
 
 from repro import models as repro_models
 from repro.core import engine
+from repro.launch import steps
 
 
 @contextlib.contextmanager
@@ -63,8 +66,20 @@ def half_batch():
             return model.loss(params, dict(batch, labels=kept))
         return dataclasses.replace(model, loss=loss)
 
-    with _patched(repro_models, "build", build_half):
+    # launch/steps.py imported ``build`` by name
+    with _patched(repro_models, "build", build_half), \
+            _patched(steps, "build", build_half):
         yield
 
 
-FAULTS = {"frozen": frozen, "half_batch": half_batch}
+@contextlib.contextmanager
+def no_exchange():
+    def client_0(spec, key, n_clients):
+        return lambda p: p[0]
+
+    with _patched(engine, "make_sync", client_0):
+        yield
+
+
+FAULTS = {"frozen": frozen, "half_batch": half_batch,
+          "no_exchange": no_exchange}

@@ -26,11 +26,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.chip import check, program, spec, trace
-from benchmarks.chip.reference import common, mamba2, qwen2
+from benchmarks.chip.reference import common
 from benchmarks.chip.reference.savic import SavicReference
 from benchmarks.chip.traffic.generator import RoundTraffic
 
-REFERENCES = {"qwen2": qwen2, "mamba2": mamba2}
 CHECK_ROUNDS = 3
 SAMPLE = 1 << 18            # elements of a leaf compared one by one
 TRACE_SECONDS = 2.0         # rounds traced after the window: at least this
@@ -56,11 +55,19 @@ def _round(prog, state, traffic, r):
     return state, loss, time.perf_counter() - t
 
 
+def _reference(conf):
+    return spec.reference(conf["model_type"])
+
+
+def _shapes(conf):
+    """The reference's parameter tree, as shapes."""
+    return common.shapes_from_table(_reference(conf).param_table(conf))
+
+
 def weights(conf):
     """The seeded weights of one replica, ``make(key)``, checked against the
     program's own parameter tree leaf for leaf."""
-    ref = REFERENCES[conf["model_type"]]
-    ours = common.shapes_from_table(ref.param_table(conf))
+    ours = _shapes(conf)
     theirs = program.param_shapes(program.model_config(conf))
     if jax.tree.structure(ours) != jax.tree.structure(theirs) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in
@@ -68,16 +75,14 @@ def weights(conf):
         raise ValueError(f"the benchmark's {conf['model_type']} weights do "
                          f"not match the program's parameter tree:\n"
                          f"{ours}\n{theirs}")
-    return functools.partial(ref.init_params, cfg=conf)
+    return functools.partial(_reference(conf).init_params, cfg=conf)
 
 
 def leaf_names(conf) -> list:
     """'/'-joined paths of the parameter leaves, in the order the readings
     list them."""
-    shapes = common.shapes_from_table(
-        REFERENCES[conf["model_type"]].param_table(conf))
     return ["/".join(str(k.key) for k in path) for path, _ in
-            jax.tree_util.tree_flatten_with_path(shapes)[0]]
+            jax.tree_util.tree_flatten_with_path(_shapes(conf))[0]]
 
 
 def sample_index(conf, seed) -> list:
@@ -93,9 +98,8 @@ def sample_index(conf, seed) -> list:
 
 def leaf_sizes(conf) -> list:
     """Elements of each parameter leaf, in tree-flattening order."""
-    return [math.prod(leaf.shape) for leaf in jax.tree.leaves(
-        common.shapes_from_table(
-            REFERENCES[conf["model_type"]].param_table(conf)))]
+    return [math.prod(leaf.shape)
+            for leaf in jax.tree.leaves(_shapes(conf))]
 
 
 def read_leaves(tree, index):
@@ -139,20 +143,20 @@ def check_rounds(prog, cell, seed):
         if r == 0:
             grad = _fetched(prog.first_grad(state, read_leaves, index))
     change = _fetched(prog.change(state, key, read_leaves, index))
-    return state, traffic, _readings(losses, grad, change, cell.config)
+    return state, traffic, _readings(losses, grad, change, cell)
 
 
-def _readings(losses, grad, change, conf) -> dict:
+def _readings(losses, grad, change, cell) -> dict:
     return {"losses": losses, "grad": grad[0], "grad_sample": grad[1],
             "change": change[0], "change_sample": change[1],
-            "sizes": leaf_sizes(conf)}
+            "sizes": leaf_sizes(cell.config)}
 
 
 def reference_side(cell, devices, seed, precision=None):
     """The plain reference's readings of the check rounds, at the
     configuration's matmul precision (or at ``precision``)."""
     conf = cell.config
-    ref = REFERENCES[conf["model_type"]]
+    ref = _reference(conf)
     make = weights(conf)
     key = common.seed_key(seed)
     traffic = RoundTraffic(cell.mix, conf["vocab_size"], seed)
@@ -171,14 +175,15 @@ def reference_side(cell, devices, seed, precision=None):
         return b["tokens"], b["labels"]
 
     with jax.default_matmul_precision(precision or conf["matmul_precision"]):
-        params = jax.jit(start, out_shardings=jax.sharding.
-                         SingleDeviceSharding(devices[0]))(key)
+        params = jax.jit(start, out_shardings=getattr(
+            run, "everywhere",
+            jax.sharding.SingleDeviceSharding(devices[0])))(key)
         losses, grad, x = run.run(
             params, batch_at, CHECK_ROUNDS,
             lambda g: _fetched(read(jax.tree.map(jnp.abs, g), index)))
         change = _fetched(jax.jit(lambda x, k, i: read_leaves(jax.tree.map(
             lambda a, b: a - b, x, start(k)), i))(x, key, index))
-    return _readings(losses, grad, change, conf)
+    return _readings(losses, grad, change, cell)
 
 
 def run_cell(cell, seed, seconds, want_trace, devices, t_start, peaks):
@@ -206,9 +211,11 @@ def run_cell(cell, seed, seconds, want_trace, devices, t_start, peaks):
     median = float(np.median(times))
     slow = [(i, round(1e3 * t, 1)) for i, t in enumerate(times)
             if t > 1.5 * median]
+    round_ms_p90 = 1e3 * float(np.percentile(times, 90))
     log(f"window {elapsed:.3f} s, {len(times)} rounds, {tokens_per_s:.1f} "
         f"tokens/s; round from dispatch to loss: median "
-        f"{1e3 * median:.2f} ms, max {1e3 * max(times):.2f} ms; outside it "
+        f"{1e3 * median:.2f} ms, p90 {round_ms_p90:.3f} ms, max "
+        f"{1e3 * max(times):.2f} ms; outside it "
         f"{elapsed - sum(times):.3f} s; rounds over 1.5x the median "
         f"(index, ms): {slow}")
     # (the CPU of the tests reports no memory statistics)
@@ -264,7 +271,7 @@ def run_cell(cell, seed, seconds, want_trace, devices, t_start, peaks):
                                "idle_gaps": trace.idle_gaps(events)}
     else:
         values = {"setup_s": setup_s, "train_tokens_per_s": tokens_per_s,
-                  "round_ms_p90": 1e3 * float(np.percentile(times, 90))}
+                  "round_ms_p90": round_ms_p90}
         result["metrics"] = {m["name"]: {"value": values[m["name"]],
                                          "unit": m["unit"]}
                              for m in cell.end_to_end}

@@ -13,16 +13,20 @@ the last local step: D2 <- b_t D2 + (1 - b_t) g_avg**2, with Adam's
 debiased b_t = (b - b**(t+1)) / (1 - b**(t+1)) at the t-th update (so the
 first update sets D2 = g_avg**2). D2 starts at 1 and m at 0.
 
-All arithmetic is in the parameters' dtype (float32 for the check). Clients
-are computed one after another, each on ``devices[c % len]``, and
-the averages leaf by leaf on ``devices[0]``, so a configuration whose
-clients do not fit one chip together still fits a host.
+All arithmetic is in the parameters' dtype (float32 for the check). On one
+device the clients are computed one after another and the averages leaf by
+leaf. On several, one client a device (as the program's four-chip cells
+run), each client is computed on its own device and each average leaf by
+leaf on every device from all the clients' copies of the leaf, summed in
+client order as on one device, so every device holds the server's state
+and no tree goes through the host.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 class SavicReference:
@@ -32,7 +36,8 @@ class SavicReference:
 
     ``loss(params, tokens, labels)`` is the plain model's loss;
     ``batch_at(r)`` gives round r's numpy tokens and labels, each
-    (M, H, b, S). ``params`` is consumed (its buffers are donated).
+    (M, H, b, S). ``params`` is consumed (its buffers are donated); on
+    several devices it is best made on all of them (``self.everywhere``).
     """
 
     def __init__(self, loss, *, gamma, beta1, alpha, beta2, devices):
@@ -58,6 +63,24 @@ class SavicReference:
                                            * jnp.square(gsum * inv_m)
                                            ).astype(d2.dtype),
             donate_argnums=0)
+        if len(self.devices) > 1:
+            mesh = Mesh(np.array(self.devices), ("clients",))
+            self.everywhere = NamedSharding(mesh, P())
+            self._one_a_device = NamedSharding(mesh, P("clients"))
+            self._lead = jax.jit(lambda a: a[None], donate_argnums=0)
+
+            def client_sum(a, scale):
+                acc = a[0]
+                for c in range(1, a.shape[0]):
+                    acc = acc + a[c]
+                return acc if scale is None else \
+                    (acc * scale).astype(acc.dtype)
+
+            self._client_sum = jax.jit(client_sum,
+                                       out_shardings=self.everywhere)
+            self._fill = jax.jit(
+                lambda t, v: jax.tree.map(lambda a: jnp.full_like(a, v), t),
+                static_argnums=1, out_shardings=self.everywhere)
 
     def _mean_leaves(self, trees):
         """Client average, leaf by leaf on devices[0]; frees the inputs."""
@@ -77,6 +100,9 @@ class SavicReference:
         return jax.tree.unflatten(treedef, out)
 
     def run(self, params, batch_at, rounds: int, read_grad):
+        if len(self.devices) > 1:
+            return self._run_one_a_device(params, batch_at, rounds,
+                                          read_grad)
         dev0 = self.devices[0]
         x = jax.device_put(params, dev0)
         del params
@@ -127,3 +153,75 @@ class SavicReference:
             del g_sum
         return round_losses, g_read, x
 
+
+    def _across(self, trees, scale=None):
+        """Leaf by leaf, the clients' sum in client order (times ``scale``)
+        on every device, from ``trees``, client c's tree on device c;
+        frees the inputs."""
+        leaves = [jax.tree.leaves(t) for t in trees]
+        treedef = jax.tree.structure(trees[0])
+        trees.clear()
+        out = []
+        for i in range(len(leaves[0])):
+            parts = [self._lead(client[i]) for client in leaves]
+            for client in leaves:
+                client[i] = None
+            stacked = jax.make_array_from_single_device_arrays(
+                (len(parts),) + parts[0].shape[1:], self._one_a_device,
+                parts)
+            del parts
+            out.append(self._client_sum(stacked, scale))
+            del stacked
+        return jax.tree.unflatten(treedef, out)
+
+    def _run_one_a_device(self, params, batch_at, rounds, read_grad):
+        devs = self.devices
+        x = jax.device_put(params, self.everywhere)
+        del params
+        m = self._fill(x, 0.0)
+        d2 = self._fill(x, 1.0)
+
+        def mine(tree, c):
+            """Device c's copy of a tree held on every device."""
+            return jax.tree.map(lambda a: {s.device: s.data for s in
+                                           a.addressable_shards}[devs[c]],
+                                tree)
+
+        round_losses, g_read = [], None
+        for r in range(rounds):
+            tokens, labels = batch_at(r)
+            M, H = tokens.shape[:2]
+            if M != len(devs):
+                raise ValueError(f"{M} clients on {len(devs)} devices: on "
+                                 f"several devices the reference runs one "
+                                 f"client a device")
+            xs = [mine(x, c) for c in range(M)]
+            ms = [mine(m, c) for c in range(M)]
+            d2s = [mine(d2, c) for c in range(M)]
+            del x, m
+            losses, gs = [], [None] * M
+            for h in range(H):
+                for c in range(M):
+                    xs[c], ms[c], g, value = self._step_donating(
+                        xs[c], ms[c], d2s[c],
+                        jax.device_put(tokens[c, h], devs[c]),
+                        jax.device_put(labels[c, h], devs[c]))
+                    losses.append(value)
+                    if h == H - 1:
+                        gs[c] = g
+                    del g
+            del d2s
+            round_losses.append(float(np.mean(
+                [float(v) for v in jax.device_get(losses)])))
+            inv_m = np.float32(1.0 / M)
+            x = self._across(xs, inv_m)
+            m = self._across(ms, inv_m)
+            g_sum = self._across(gs)
+            b = self.beta2
+            beta = np.float32((b - b ** (r + 1)) / (1.0 - b ** (r + 1)))
+            if r == 0:
+                g_read = read_grad(jax.tree.map(lambda a: a * inv_m, g_sum))
+            d2 = jax.tree.map(lambda di, gi: self._d2(di, gi, beta, inv_m),
+                              d2, g_sum)
+            del g_sum
+        return round_losses, g_read, x

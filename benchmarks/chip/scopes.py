@@ -106,8 +106,7 @@ def scope_ms(ev: trace.Events, scopes: dict, scope: str):
     round started in the window."""
     inside = {n for n, op in scopes.items() if in_scope(op, scope)}
     lo, hi = ev.window
-    rounds = sum(name == "dispatch" and lo <= s < hi
-                 for s, _, name in ev.host_spans)
+    rounds = trace.rounds(ev)
     if not inside or not rounds:
         return None
     total = 0.0

@@ -3,8 +3,14 @@ root names the cell's configuration, traffic mix and chips; the
 configuration is ``configs/<config>.json``, the mix
 ``traffic/mixes/<traffic>.json``, and the cell's method and check
 limits ``workloads/<cell>.json``. A per-layer metric is read by
-``metrics/<name>.py``. A new cell, configuration or metric is a new file;
-no file that is already there changes.
+``metrics/<name>.py``. A configuration's ``model_type`` names two files:
+``model_types/<model_type>.py``, what the program needs to know of the
+type (its configuration keys as the program names them, the depth key,
+the numbers the file sets, any further check, the forward FLOPs of a
+token), and ``reference/<model_type>.py``, its plain reference
+(``param_table``, ``init_params``, ``loss``). A new cell, configuration
+or metric is a new file, and a new model type those two; no file that is
+already there changes.
 """
 from __future__ import annotations
 
@@ -16,6 +22,8 @@ import os
 from benchmarks.chip.traffic.generator import Mix, load_mix
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+# where ``model_types/`` and ``reference/`` are found, read at each lookup
+TYPES_DIR = HERE
 
 
 @dataclasses.dataclass
@@ -68,11 +76,34 @@ def load_cell(root: str, name: str, here: str = HERE) -> Cell:
                 workload=workload, end_to_end=e2e, per_layer=per_layer)
 
 
-def metric_reader(name: str, here: str = HERE):
-    """The ``read(ctx)`` function of ``metrics/<name>.py``."""
-    path = os.path.join(here, "metrics", f"{name}.py")
+def _module(here: str, kind: str, name: str):
+    """The module ``<here>/<kind>/<name>.py``."""
+    path = os.path.join(here, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"no {kind} file {path}")
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, here: str = HERE):
+    """The ``read(ctx)`` function of ``metrics/<name>.py``."""
+    return _module(here, "metrics", name).read
+
+
+def model_type(kind: str):
+    """``model_types/<kind>.py``: ``FIELDS`` (configuration key ->
+    ``ModelConfig`` field, dotted for a nested one), ``DEPTH`` (the key of
+    the layer count), ``SET`` (configuration key -> field, numbers the file
+    sets on the registered architecture), ``forward_flops(conf, S)`` and,
+    where the type has one, ``check(cfg)``."""
+    return _module(TYPES_DIR, "model_types", kind)
+
+
+def reference(kind: str):
+    """``reference/<kind>.py``: the plain reference of the model type,
+    ``param_table(conf)``, ``init_params(key, cfg=conf)`` and
+    ``loss(conf, params, tokens, labels)``."""
+    return _module(TYPES_DIR, "reference", kind)

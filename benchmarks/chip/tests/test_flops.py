@@ -1,9 +1,9 @@
-"""Model FLOPs a training token, worked out by hand for both
+"""Model FLOPs a training token, worked out by hand for the
 configurations at their cells' sequence lengths."""
 import json
 import os
 
-from benchmarks.chip import flops
+from benchmarks.chip import flops, spec
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -57,5 +57,6 @@ def test_mamba2_1p3b_l7_at_4096():
 
 def test_chunk_is_cut_to_a_short_sequence():
     conf = _conf("mamba2-1.3b-l7")
-    assert flops.mamba2_forward(conf, 64) == flops.mamba2_forward(
-        dict(conf, chunk_size=64), 64)
+    forward = spec.model_type("mamba2").forward_flops
+    assert forward(conf, 64) == forward(dict(conf, chunk_size=64), 64)
+

@@ -100,3 +100,78 @@ def test_readers():
     assert mfu == pytest.approx(100 * 2_292_240_384 * 1e4 / (2 * 197e12))
     ctx.events = None
     assert spec.metric_reader("device_idle_pct.train")(ctx) is None
+
+
+@pytest.mark.parametrize("event, kind", [
+    (AR, ("all-reduce", "")),
+    ("%all-reduce-start.2 = f32[8]{0} all-reduce-start(f32[8]{0} %p), "
+     "to_apply=%add", ("all-reduce", "-start")),
+    ("%ag-done = (f32[8]{0}, f32[32]{0}) all-gather-done((f32[8]{0}, "
+     "f32[32]{0}) %ag-start)", ("all-gather", "-done")),
+    ("%collective-permute.1 = f32[8]{0} collective-permute(f32[8]{0} %p), "
+     "source_target_pairs={{0,1}}", ("collective-permute", "")),
+    ("reduce-scatter.4", ("reduce-scatter", "")),
+    ("all-to-all-start", ("all-to-all", "-start")),
+    # a fusion that reads an all-reduce's result is no collective
+    ("%fusion.3 = f32[8]{0} fusion(f32[8]{0} %all-reduce.7), kind=kLoop",
+     None),
+    ("all-reducer.1", None),
+    ("%fusion.1 = f32[8]{0} fusion(f32[8]{0} %a), kind=kLoop", None),
+])
+def test_collective_by_opcode_or_name(event, kind):
+    assert trace.collective(event) == kind
+
+
+def _collective_events():
+    f = "%fusion.{} = f32[8]{{0}} fusion(f32[8]{{0}} %a), kind=kLoop".format
+    start = ("%all-reduce-start.5 = f32[8]{0} all-reduce-start(f32[8]{0} "
+             "%p), to_apply=%add")
+    done = ("%all-reduce-done.5 = f32[8]{0} all-reduce-done(f32[8]{0} "
+            "%all-reduce-start.5)")
+    permute = ("%collective-permute.1 = f32[8]{0} collective-permute("
+               "f32[8]{0} %p), source_target_pairs={{0,1}}")
+    gather = ("%all-gather.2 = f32[32]{0} all-gather(f32[8]{0} %p), "
+              "dimensions={0}")
+    loop = ("%while.3 = (s32[], f32[8]{0}) while((s32[], f32[8]{0}) %t), "
+            "condition=%c, body=%b")
+    return trace.Events(
+        window=(0.0, 10.0),
+        device_ops=[
+            [(0.0, 10.0, loop),
+             # overlapped on [2, 3] by fusion.1: exposed 1
+             (1.0, 3.0, f(1)), (2.0, 4.0, AR),
+             # in flight from 5 to 7, compute on [5.1, 6]: exposed 1.1
+             (5.0, 5.1, start), (5.1, 6.0, f(2)), (6.5, 7.0, done),
+             # hidden under fusion.3: exposed 0
+             (7.9, 9.0, f(3)), (8.0, 8.5, permute)],
+            # exposed whole, the second past the window's end by 0.5
+            [(1.0, 2.0, gather), (9.5, 10.5, "reduce-scatter.4"),
+             (2.0, 3.0, f(4))],
+            # no collective on this chip
+            [(1.0, 2.0, f(5))],
+        ],
+        host_spans=[(0.0, 0.1, "dispatch"), (5.0, 5.1, "dispatch"),
+                    (10.0, 10.1, "dispatch")])
+
+
+def test_exposed_collectives_per_chip():
+    got = trace.exposed_collective_s(_collective_events())
+    assert got[0] == pytest.approx(2.1)
+    assert got[1] == pytest.approx(1.5)
+    assert got[2] is None
+    assert trace.rounds(_collective_events()) == 2
+
+
+def test_the_collective_reader():
+    read = spec.metric_reader("collective_exposed_ms.train")
+    ev = _collective_events()
+    # (2.1 + 1.5 + 0) s over 3 chips, 2 rounds
+    assert read(types.SimpleNamespace(events=ev)) == pytest.approx(600.0)
+    # one chip, no collective: nothing to read
+    ev.device_ops = ev.device_ops[2:]
+    assert read(types.SimpleNamespace(events=ev)) is None
+    assert read(types.SimpleNamespace(events=None)) is None
+    # collectives, no round in the window
+    ev = _collective_events()
+    ev.host_spans = []
+    assert read(types.SimpleNamespace(events=ev)) is None

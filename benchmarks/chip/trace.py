@@ -8,7 +8,10 @@ plane (``/device:TPU:<id>``) has a line ``XLA Ops`` with one event per
 operation run on it. Its busy time is the union of those events' intervals
 inside the traced window (a loop's event covers the operations it runs);
 an idle gap is an interval of the window that no operation covers, named
-by the host span that overlaps it most.
+by the host span that overlaps it most. A collective (an all-reduce,
+all-gather, reduce-scatter, collective-permute or all-to-all, or the
+interval from the start of an asynchronous one to its done) is exposed
+where no other operation runs on that chip.
 """
 from __future__ import annotations
 
@@ -23,6 +26,13 @@ WINDOW_SPAN = "bench_trace"
 OPS_LINE = "XLA Ops"
 # operations whose event spans the operations they run (a scan's loop)
 CONTAINER = re.compile(r"^(while|conditional|call)\b")
+# a collective, by its HLO opcode ('%ar.3 = f32[8]{0} all-reduce(...') or,
+# where the event carries no text, by its name ('all-reduce-start.3')
+_KINDS = r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
+_COLLECTIVE_OP = re.compile(rf"\s({_KINDS})(-start|-done)?\(")
+_COLLECTIVE_NAME = re.compile(rf"^({_KINDS})(-start|-done)?(?:[.\-]|$)")
+# the first operand of an asynchronous done: the start it waits for
+_OPERAND = re.compile(r"-done\([^%]*%([\w.\-]+)")
 
 
 @dataclasses.dataclass
@@ -109,6 +119,69 @@ def busy_s(ev: Events) -> list:
     """Seconds of the window in which an operation ran, per chip."""
     lo, hi = ev.window
     return [length(union(ops, lo, hi)) for ops in ev.device_ops]
+
+
+def overlap(a, b) -> float:
+    """Length of the intersection of two merged interval lists."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def rounds(ev: Events) -> int:
+    """Rounds traced: the ``dispatch`` spans that start in the window."""
+    lo, hi = ev.window
+    return sum(name == "dispatch" and lo <= s < hi
+               for s, _, name in ev.host_spans)
+
+
+def collective(event_name: str):
+    """(kind, '' | '-start' | '-done') of a collective operation's event,
+    None for any other."""
+    text = event_name.split(" = ", 1)
+    m = _COLLECTIVE_OP.search(" " + text[1]) if len(text) == 2 else \
+        _COLLECTIVE_NAME.match(op_name(event_name))
+    return (m.group(1), m.group(2) or "") if m else None
+
+
+def exposed_collective_s(ev: Events) -> list:
+    """Per chip, seconds of the window in which a collective was under
+    way and no other operation ran: the union of the collectives'
+    intervals (an asynchronous one from its start to its done), less
+    what the other operations cover. A loop or call, whose event holds
+    the operations it runs, is no other operation. None for a chip with
+    no collective in the window."""
+    lo, hi = ev.window
+    out = []
+    for ops in ev.device_ops:
+        coll, other, started = [], [], {}
+        for s, e, name in ops:
+            kind = collective(name)
+            n = op_name(name)
+            if kind is None:
+                if not CONTAINER.match(n):
+                    other.append((s, e))
+                continue
+            coll.append((s, e))
+            if kind[1] == "-start":
+                started.setdefault(kind[0], {})[n] = s
+            elif kind[1] == "-done":
+                waits = started.get(kind[0], {})
+                m = _OPERAND.search(name)
+                begin = waits.pop(m.group(1), None) if m else None
+                if begin is None and waits:       # no operand: the latest
+                    begin = waits.pop(list(waits)[-1])
+                if begin is not None:
+                    coll.append((begin, e))
+        merged = union(coll, lo, hi)
+        out.append(length(merged) - overlap(merged, union(other, lo, hi))
+                   if merged else None)
+    return out
 
 
 def top_device_ops(ev: Events, k: int = 10) -> list:
