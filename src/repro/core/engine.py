@@ -537,8 +537,11 @@ def average_params(state):
 
 
 def _leaf_drift(p):
-    mean = p.mean(axis=0, keepdims=True)
-    return jnp.sum((p - mean) ** 2)
+    # the clients' mean and the sum over them cross clients as the sync's
+    # average does: on a mesh, one client a chip, all-reduces of the leaf
+    with jax.named_scope("exchange"):
+        mean = p.mean(axis=0, keepdims=True)
+        return jnp.sum((p - mean) ** 2)
 
 
 def client_drift(params_m):
@@ -1097,6 +1100,11 @@ def make_sync(spec: SyncSpec, key, n_clients: int):
     sync_dtype bytes; the master-dtype cast happens locally after (quantized
     averaging — same family as the quantization line of related work [19,20];
     sync noise ~2^-8 relative for bf16).
+
+    The average runs under the named scope ``exchange``, as the client
+    drift's mean does (``_leaf_drift``): where client state crosses
+    clients, so on a mesh, one client a chip, the partitioner's all-reduces
+    carry that scope.
     """
     M = n_clients
     w_part = participation_weights(spec, key, M)
@@ -1108,12 +1116,16 @@ def make_sync(spec: SyncSpec, key, n_clients: int):
     if spec.sync_dtype:
         sd = jnp.dtype(spec.sync_dtype)
 
-        def avg(p):
+        def mean(p):
             q = jax.lax.optimization_barrier(p.astype(sd))
             a = _wmean(q)
             return jax.lax.optimization_barrier(a)
     else:
-        avg = _wmean
+        mean = _wmean
+
+    def avg(p):
+        with jax.named_scope("exchange"):
+            return mean(p)
     return avg
 
 
