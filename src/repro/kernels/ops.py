@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.kernels import decode_step as _ds
 from repro.kernels import flash_attention as _fa
+from repro.kernels import fused_ce as _fce
 from repro.kernels import quantize_update as _qu
 from repro.kernels import scaled_update as _su
 from repro.kernels import ssd_scan as _ssd
@@ -97,6 +98,22 @@ def sync_average(x, w, *, drift=False, layer_major=False):
     mean) when ``drift``, else None."""
     return _sa.sync_average(x, w, drift=drift, layer_major=layer_major,
                             interpret=_interpret())
+
+
+fused_ce_tiles = _fce.tiles
+
+
+def fused_cross_entropy(h, w, labels, *, tied, scale, v_real):
+    """The LM head and its mean cross-entropy in one kernel pair that never
+    writes the (T, V) logits (``fused_ce.py``): h (T, d), w the tied (V, d)
+    table or the untied (d, V) head, labels (T,). The matmuls take the
+    precision XLA's default gives an fp32 matmul on the backend: one
+    bfloat16 pass on a TPU, fp32 on the CPU."""
+    interpret = _interpret()
+    return _fce.fused_cross_entropy(
+        h, w, labels, tied=tied, scale=scale, v_real=v_real,
+        mxu_dtype=jnp.float32 if interpret else jnp.bfloat16,
+        interpret=interpret)
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,

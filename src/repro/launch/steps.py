@@ -20,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ModelConfig, ShapeConfig, get_config, get_shape
 from repro.core import PrecondConfig, SavicConfig, engine, objectives, savic
 from repro.models import ModelCallConfig, batch_struct, build
+from repro.models.layers import head_path
 from repro.sharding import (AxisPlan, batch_pspecs, cache_pspecs,
                             params_pspecs, plan_for, serve_batch_pspecs)
 
@@ -100,10 +101,13 @@ def build_train_step(arch: str, shape: ShapeConfig, mesh, *, mode: str = "auto",
     if cfg.moe and call.moe_shard is None:
         call = dataclasses.replace(
             call, moe_shard=_moe_shard_fn(cfg, mesh, plan))
+    call = dataclasses.replace(call, devices=mesh.size)
     model = build(cfg, call)
     M = plan.clients(mesh) if plan.client else 1
     assert shape.global_batch % M == 0, (shape.global_batch, M)
     b_client = shape.global_batch // M
+    # the loss's LM head: the fused kernel on one TPU device, else jnp
+    head = head_path(cfg, b_client * shape.seq_len, devices=mesh.size)
     H = h_local or savic_round_h(shape)
 
     spec = engine_spec or _method_engine_spec(method, pc_kind, sv)
@@ -257,7 +261,7 @@ def build_train_step(arch: str, shape: ShapeConfig, mesh, *, mode: str = "auto",
         donate=(0,),
         meta={"mode": mode, "method": method, "clients": M, "h_local": H,
               "b_client": b_client, "cfg": cfg, "plan": plan,
-              "engine_spec": spec, **het_meta},
+              "engine_spec": spec, "head": head, **het_meta},
     )
 
 

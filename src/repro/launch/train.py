@@ -53,6 +53,7 @@ from repro.core import PrecondConfig, SavicConfig, engine, objectives, savic
 from repro.data import LMRoundLoader, TokenStream
 from repro.data import federated
 from repro.models import ModelCallConfig, build
+from repro.models.layers import head_path
 from repro.utils.compile_cache import enable_compile_cache
 
 
@@ -366,6 +367,10 @@ def main(argv=None):
           f"{plan['kernel_bytes'] / 1e6:.3f} MB, jnp {len(plan['jnp'])} "
           f"leaves {plan['jnp_bytes'] / 1e6:.3f} MB (one replica's tree)",
           flush=True)
+    # the loss's LM head: the fused kernel on one TPU device, else jnp
+    log.setup["head"] = built.meta["head"] if mesh is not None else \
+        head_path(cfg, args.batch * args.seq)
+    print(f"[train] lm head: {log.setup['head']}", flush=True)
 
     state = engine.init_state(jax.random.PRNGKey(args.seed), model.init, spec,
                               M)

@@ -404,3 +404,39 @@ def cross_entropy(logits, labels, vocab_size):
     nll = logz - gold
     mask = (labels >= 0).astype(jnp.float32)
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+
+def head_path(cfg: ModelConfig, tokens: int, *, devices: int = 1,
+              backend: Optional[str] = None) -> str:
+    """How ``unembed_cross_entropy`` computes the loss of ``tokens`` tokens:
+    "fused", one Pallas kernel pair that never writes the (T, V) logits
+    (``kernels/fused_ce.py``), when the backend is a TPU, the step is built
+    for one device (a ``pallas_call`` over GSPMD-sharded activations would
+    be gathered onto every device, as ``engine._one_pass`` says of the
+    sync), the head has no softcap, matmuls take XLA's default precision
+    and the shapes tile (``ops.fused_ce_tiles``); else "jnp", ``unembed``
+    then ``cross_entropy``."""
+    from repro.kernels import ops as kops
+    fused = ((backend or jax.default_backend()) == "tpu" and devices == 1
+             and not cfg.logit_softcap
+             and jax.config.jax_default_matmul_precision in (
+                 None, "default", "bfloat16")
+             and kops.fused_ce_tiles(tokens, padded_vocab(cfg.vocab_size),
+                                     cfg.d_model) is not None)
+    return "fused" if fused else "jnp"
+
+
+def unembed_cross_entropy(p, x, labels, cfg: ModelConfig, dtype,
+                          devices: int = 1):
+    """``cross_entropy(unembed(p, x, ...), labels, ...)``, by the path that
+    ``head_path`` picks for a step built for ``devices`` devices."""
+    if head_path(cfg, labels.size, devices=devices) == "jnp":
+        return cross_entropy(unembed(p, x, cfg, dtype), labels,
+                             cfg.vocab_size)
+    from repro.kernels import ops as kops
+    with jax.named_scope("lm_head"), jax.named_scope("fused_ce"):
+        w = p["table"] if cfg.tie_embeddings else p["head"]
+        return kops.fused_cross_entropy(
+            x.astype(dtype).reshape(labels.size, -1), w.astype(dtype),
+            labels.reshape(-1), tied=cfg.tie_embeddings,
+            scale=cfg.d_model ** -0.5, v_real=cfg.vocab_size)

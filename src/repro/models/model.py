@@ -25,8 +25,9 @@ import jax.numpy as jnp
 
 from repro.configs import ModelConfig
 from repro.models import transformer as T
-from repro.models.layers import (AttnCall, cross_entropy, embed, init_embed,
-                                 init_rmsnorm, padded_vocab, rmsnorm, unembed)
+from repro.models.layers import (AttnCall, embed, init_embed, init_rmsnorm,
+                                 padded_vocab, rmsnorm, unembed,
+                                 unembed_cross_entropy)
 
 
 @dataclasses.dataclass
@@ -42,6 +43,9 @@ class ModelCallConfig:
     use_decode_kernel: bool = False  # fused Pallas decode attention + sampling
     softcap: float = 0.0
     exact_moe: bool = False         # no MoE capacity drops (tests)
+    # devices of the mesh the step is built for: the loss's fused LM head
+    # runs on one only (layers.head_path)
+    devices: int = 1
     # optional residual-stream sharding hook: fn((B,S,d)) -> constrained array.
     # Used by launch/steps.py to pin batch-parallel activations when parameter
     # sharding would otherwise win GSPMD propagation (paper_fsdp mode).
@@ -109,7 +113,7 @@ def build(cfg: ModelConfig, call: Optional[ModelCallConfig] = None) -> Model:
     def _constrain(x):
         return call.act_shard(x) if call.act_shard is not None else x
 
-    def _forward_logits(params, batch):
+    def _forward_hidden(params, batch):
         x, labels, _ = _residual_input(params, batch)
         x = _constrain(x)
         S = x.shape[1]
@@ -117,15 +121,16 @@ def build(cfg: ModelConfig, call: Optional[ModelCallConfig] = None) -> Model:
         y, _, aux = T.forward(params["blocks"], cfg, x, positions,
                               _attncall(S), dtype, want_cache=False,
                               remat=call.remat)
-        y = rmsnorm(params["final_norm"], y, cfg.norm_eps)
-        return unembed(params["embed"], y, cfg, dtype), labels, aux
+        return rmsnorm(params["final_norm"], y, cfg.norm_eps), labels, aux
 
     def loss(params, batch):
-        logits_, labels, aux = _forward_logits(params, batch)
-        return cross_entropy(logits_, labels, cfg.vocab_size) + aux
+        y, labels, aux = _forward_hidden(params, batch)
+        return unembed_cross_entropy(params["embed"], y, labels, cfg, dtype,
+                                     devices=call.devices) + aux
 
     def logits(params, batch):
-        return _forward_logits(params, batch)[0].astype(jnp.float32)
+        y = _forward_hidden(params, batch)[0]
+        return unembed(params["embed"], y, cfg, dtype).astype(jnp.float32)
 
     def prefill(params, batch):
         x, _, _ = _residual_input(params, batch)

@@ -19,7 +19,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels import decode_step, flash_attention, quantize_update
+from repro.kernels import decode_step, flash_attention, fused_ce
+from repro.kernels import quantize_update
 from repro.kernels import scaled_update, sync_average
 from repro.models.layers import padded_vocab
 
@@ -140,3 +141,27 @@ def test_sync_average_compiles(one_chip, leaf):
     if not layer_major:
         assert compiled.memory_analysis().alias_size_in_bytes \
             == 4 * math.prod(shape)
+
+
+@pytest.mark.parametrize("head", ["tied-qwen2", "untied-mamba2"])
+def test_fused_cross_entropy_compiles(one_chip, head):
+    """The fused LM head and cross-entropy, forward and backward under
+    ``vmap`` over M = 2 clients, at the one-chip cells' shapes: qwen2-0.5b's
+    tied 153,600-row table at 512 tokens, mamba2-1.3b's untied (2048,
+    51,200) head at 4,096. Both kernels stay within their VMEM limit and
+    the round step's temporaries hold no (T, V) logits."""
+    if head == "tied-qwen2":
+        cfg, T = CFG, 512
+    else:
+        cfg, T = get_config("mamba2-1.3b"), 4096
+    tied, d, V = cfg.tie_embeddings, cfg.d_model, padded_vocab(cfg.vocab_size)
+    fn = jax.vmap(jax.value_and_grad(
+        lambda h, w, y: fused_ce.fused_cross_entropy(
+            h, w, y, tied=tied, scale=d ** -0.5, v_real=cfg.vocab_size),
+        (0, 1)))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((M, T, d), F32), ((M, V, d) if tied else (M, d, V), F32),
+        ((M, T), I32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < M * T * V * 4 / 8
