@@ -545,27 +545,10 @@ def migrate(root=None, write=False):
         docs["async"] = _doc("async", legacy.get("config", {}),
                              ["method", "arm"], rows, rev)
 
-    # kernels: one legacy file -> fused + sharded docs; micro rows lived only
-    # in results/bench/kernels.csv
+    # kernels: the micro rows lived only in results/bench/kernels.csv
     legacy = _load("BENCH_kernels.json")
     if legacy and "schema_version" not in legacy:
         rev = legacy.get("git_rev")
-        docs["kernels_fused"] = _doc(
-            "kernels_fused", legacy.get("config", {}), ["case"],
-            _rows_from_mapping(legacy["cases"], "case", rev), rev)
-        sh = legacy.get("sharded", {})
-        docs["kernels_sharded"] = _doc(
-            "kernels_sharded", sh.get("config", {}), ["plan"],
-            [make_row({"plan": plan},
-                      {"n_shards": pr["n_shards"],
-                       "collective_bytes_sharded":
-                           pr["sharded"]["collective_bytes"],
-                       "collective_bytes_naive":
-                           pr["naive"]["collective_bytes"],
-                       "collective_bytes_tree":
-                           pr["tree"]["collective_bytes"]},
-                      rev=rev or "unknown")
-             for plan, pr in sh.get("plans", {}).items()], rev)
         micro = os.path.join(root, "results", "bench", "kernels.csv")
         if os.path.exists(micro):
             rows = []

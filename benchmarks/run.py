@@ -16,8 +16,6 @@ Registered benches (axes in parentheses):
                   ``controller`` subcommand is the arm=controller slice
   comm            analytic sync-vs-DDP communication volume (arch)
   kernels         Pallas kernel µs/call, interpret mode (kernel)
-  kernels_fused   fused flat-buffer local step HBM bytes (case)
-  kernels_sharded shard-mapped fused-step collective bytes (plan)
   serve           production decode path (arch × mode)
   train_lm        federated causal-LM rounds through the production driver
                   (method; + full-shape projection rows)
@@ -1044,264 +1042,6 @@ register(BenchDef(
 
 
 # --------------------------------------------------------------------------- #
-# kernels_fused — HBM bytes, fused flat-buffer step vs pre-PR per-leaf path
-# --------------------------------------------------------------------------- #
-
-
-FUSED_BENCH_M = 8
-FUSED_BENCH_SHAPES = {"w1": (256, 128), "b1": (128,), "w2": (128, 10),
-                      "b2": (10,)}
-FUSED_BENCH_CASES = (
-    # (tag, PrecondConfig kind, D advances in-loop?, external Hutchinson stat?)
-    ("adam_local", "adam", True, False),
-    ("rmsprop_local", "rmsprop", True, False),
-    ("adagrad_local", "adagrad", True, False),
-    ("oasis_local", "oasis", True, True),
-    ("adam_global", "adam", False, False),
-)
-
-
-def _bytes_accessed(fn, *args):
-    from repro.utils.hlo_cost import xla_cost_properties
-    c = jax.jit(fn).lower(*args).compile()
-    cost = xla_cost_properties(c)
-    if "bytes accessed" not in cost:
-        # fail loudly: a silent 0 would fabricate the reduction ratio
-        raise RuntimeError("cost_analysis() has no 'bytes accessed' on "
-                           f"this backend; keys: {sorted(cost)}")
-    return float(cost["bytes accessed"]), c
-
-
-def _fused_env(ctx):
-    if "fused_env" in ctx:
-        return ctx["fused_env"]
-    from repro.utils.flatten import FlatLayout
-    M = FUSED_BENCH_M
-    k = jax.random.key(7)
-    tree = lambda i0: {name: jax.random.normal(jax.random.fold_in(k, i0 + i),
-                                               (M,) + shp)
-                       for i, (name, shp) in
-                       enumerate(FUSED_BENCH_SHAPES.items())}
-    p_t, m_t, g_t = tree(0), tree(10), tree(20)
-    d_t = jax.tree.map(lambda x: jnp.abs(x) + 0.1, tree(30))
-    h_t = tree(40)
-    layout = FlatLayout.for_tree(p_t, batch_dims=1)
-    P, Mo, G = (layout.flatten(x, batch_dims=1) for x in (p_t, m_t, g_t))
-    D = layout.flatten(d_t, batch_dims=1)
-    Hs = layout.flatten(h_t, batch_dims=1)
-    ctx["fused_env"] = dict(p_t=p_t, m_t=m_t, g_t=g_t, d_t=d_t, h_t=h_t,
-                            P=P, Mo=Mo, G=G, D=D, Hs=Hs,
-                            t_m=jnp.zeros((M,), jnp.int32))
-    _extra(ctx, clients=M,
-           leaves={nm: list(s) for nm, s in FUSED_BENCH_SHAPES.items()},
-           n_total_per_client=layout.n_total,
-           backend=jax.default_backend())
-    return ctx["fused_env"]
-
-
-def _run_fused(point, ctx):
-    """One (tag, kind, local-D, hutchinson) case of the fused-step HBM
-    comparison.  Both arms are measured with ``xla_cost_properties`` ("bytes
-    accessed") on compiled programs, summed PER LAUNCH, because HBM
-    round-trips happen at launch boundaries (full methodology in the bench
-    note / DESIGN.md §7)."""
-    from repro.core import preconditioner as PC
-    from repro.kernels import ops, ref
-
-    env = _fused_env(ctx)
-    p_t, m_t, g_t, d_t, h_t = (env[n] for n in
-                               ("p_t", "m_t", "g_t", "d_t", "h_t"))
-    P, Mo, G, D, Hs, t_m = (env[n] for n in ("P", "Mo", "G", "D", "Hs", "t_m"))
-    tag = point.coords["case"]
-    _, kind, local, hutch = next(c for c in FUSED_BENCH_CASES
-                                 if c[0] == tag)
-    M = FUSED_BENCH_M
-    pc = PC.PrecondConfig(kind=kind, alpha=1e-2)
-    squared = pc.rule == "squared"
-
-    # ---- pre-PR per-leaf kernel path ------------------------------------
-    # Verbatim launch structure of the old fused path: an XLA momentum
-    # pass, then PER LEAF (flattened to (M·n_leaf,)) a pad launch to the
-    # fixed BLOCK = 8·128·16 (the old kernel padded every ragged leaf all
-    # the way up — custom-call operands materialize, so the pad copies
-    # are real HBM traffic), the kernel launch (zeros in the momentum
-    # slot, beta1 pre-applied, dead m output — see ops.scaled_update_tree)
-    # and the [:n] slice launch back.
-    OLD_BLOCK = 8 * 128 * 16
-
-    def mom_pass(m, g):
-        return jax.tree.map(lambda mm, gg: 0.9 * mm + gg, m, g)
-
-    by_mom, c_mom = _bytes_accessed(mom_pass, m_t, g_t)
-    by_leaf = 0.0
-    c_leaf = []
-    for name in FUSED_BENCH_SHAPES:
-        n_leaf = int(np.prod(FUSED_BENCH_SHAPES[name])) * M
-        npad = (OLD_BLOCK - n_leaf % OLD_BLOCK) % OLD_BLOCK
-        flat = lambda x: x.reshape(-1)
-        args = (flat(p_t[name]), jnp.zeros((n_leaf,), jnp.float32),
-                flat(m_t[name]), flat(d_t[name]))
-        launches = []
-        if npad:
-            def pad_fn(p, z, m, d, _npad=npad):
-                pad = lambda x, v: jnp.concatenate(
-                    [x, jnp.full((_npad,), v, x.dtype)])
-                return pad(p, 0), pad(z, 0), pad(m, 0), pad(d, 1.0)
-            b, c = _bytes_accessed(pad_fn, *args)
-            by_leaf += b
-            launches.append((c, args))
-            args = tuple(np.asarray(a) for a in c(*args))
-            args = tuple(jnp.asarray(a) for a in args)
-
-        def leaf_fn(p, z, m, d):
-            return ref.scaled_update_ref(p, z, m, d, gamma=0.01,
-                                         beta1=0.0, alpha=1e-2,
-                                         squared=squared)
-        b, c = _bytes_accessed(leaf_fn, *args)
-        by_leaf += b
-        launches.append((c, args))
-        if npad:
-            outs = tuple(jnp.asarray(np.asarray(o)) for o in c(*args))
-
-            def slice_fn(po, mo, _n=n_leaf):
-                return po[:_n], mo[:_n]
-            b, c = _bytes_accessed(slice_fn, *outs)
-            by_leaf += b
-            launches.append((c, outs))
-        c_leaf.append(launches)
-    by_dpass = 0.0
-    c_dpass = None
-    if local:
-        def d_pass(d, g, h, t):
-            b = PC.beta_t(pc, t)
-            stat = h if hutch else jax.tree.map(lambda x: x ** 2, g)
-            if kind == "adagrad":
-                return jax.tree.map(lambda dd, hh: dd + hh, d, stat)
-            return jax.tree.map(lambda dd, hh: b * dd + (1.0 - b) * hh,
-                                d, stat)
-        by_dpass, c_dpass = _bytes_accessed(d_pass, d_t, g_t, h_t,
-                                            jnp.int32(0))
-    bytes_prepr = by_mom + by_leaf + by_dpass
-
-    # ---- fused flat-buffer kernel contract (one launch) ----------------
-    kw = dict(gamma=0.01, beta1=0.9, alpha=1e-2, beta2=pc.beta2,
-              kind=kind, clip="max", schedule=pc.schedule, update_d=local)
-    hstat = Hs if (local and hutch) else None
-    d_arg = D if local else D[0]
-    bytes_fused, c_fused = _bytes_accessed(
-        lambda *a: ref.fused_step_ref(*a, **kw), P, Mo, G, d_arg, hstat,
-        t_m, None)
-
-    ratio = bytes_prepr / max(bytes_fused, 1.0)
-    us_prepr = _time(lambda: [c_mom(m_t, g_t)]
-                     + [c(*a) for launches in c_leaf
-                        for c, a in launches]
-                     + ([c_dpass(d_t, g_t, h_t, jnp.int32(0))]
-                        if c_dpass else []))
-    us_oracle = _time(lambda: c_fused(P, Mo, G, d_arg, hstat, t_m, None))
-    us_interp = _time(lambda: ops.fused_local_step(
-        P, Mo, G, d_arg, hstat, t_m, None, **kw))
-    rec = {
-        "bytes_prepr_path": bytes_prepr,
-        "bytes_fused": bytes_fused,
-        "hbm_reduction_x": round(ratio, 2),
-        "launches_prepr": 1 + sum(len(l) for l in c_leaf) + (1 if local
-                                                             else 0),
-        "launches_fused": 1,
-        "us_prepr_oracle": round(us_prepr, 1),
-        "us_fused_oracle": round(us_oracle, 1),
-        "us_fused_interpret": round(us_interp, 1),
-    }
-    return [make_row(point.coords, rec)]
-
-
-def _sum_fused(doc):
-    return [(f"hbm_reduction_x_{r['coords']['case']}",
-             r["metrics"]["hbm_reduction_x"]) for r in doc["rows"]]
-
-
-register(BenchDef(
-    "kernels_fused",
-    MatrixConfig.make("kernels_fused",
-                      {"case": tuple(c[0] for c in FUSED_BENCH_CASES)}),
-    _run_fused, _sum_fused,
-    note="xla_cost_properties('bytes accessed'), summed per launch (HBM "
-         "round-trips happen at launch boundaries). pre-PR arm = the "
-         "verbatim old launch structure: momentum pass + per-leaf "
-         "pad-to-BLOCK / kernel-contract / slice launches + separate D-EMA "
-         "pass. fused arm = the fused_step_flat kernel's jnp-oracle "
-         "contract in one jit (kernel pinned to it in "
-         "tests/test_fused_step.py); interpret-mode timing is "
-         "correctness-path, not TPU perf"))
-
-
-# --------------------------------------------------------------------------- #
-# kernels_sharded — shard-mapped fused-step collective bytes (plan)
-# --------------------------------------------------------------------------- #
-
-
-SHARDED_PLANS = ("model", "fsdp", "mixed")
-
-
-def _run_kernels_sharded(point, ctx):
-    """Per-step collective bytes of the shard-mapped fused local step
-    (DESIGN.md §7) vs the naive global flat view and the tree baseline.
-    Runs benchmarks/sharded_collectives.py once in a subprocess (the worker
-    forces 8 host devices; this process keeps 1); per-plan rows come from
-    that one record."""
-    if "sharded_rec" not in ctx:
-        import subprocess
-        worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                              "sharded_collectives.py")
-        # the worker counts bytes on virtual CPU devices: it never competes
-        # for an accelerator this process may hold
-        r = subprocess.run([sys.executable, worker], capture_output=True,
-                           text=True, timeout=560,
-                           env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        if r.returncode != 0:
-            raise RuntimeError(
-                f"sharded_collectives worker failed:\n{r.stderr}")
-        ctx["sharded_rec"] = json.loads(r.stdout.strip().splitlines()[-1])
-        rec = ctx["sharded_rec"]
-        _extra(ctx, n_devices=rec["n_devices"], clients=rec["clients"],
-               leaves=rec["leaves"])
-    pr = ctx["sharded_rec"]["plans"][point.coords["plan"]]
-    return [make_row(point.coords,
-                     {"n_shards": pr["n_shards"],
-                      "collective_bytes_sharded":
-                          pr["sharded"]["collective_bytes"],
-                      "collective_bytes_naive":
-                          pr["naive"]["collective_bytes"],
-                      "collective_bytes_tree":
-                          pr["tree"]["collective_bytes"]})]
-
-
-def _sum_sharded(doc):
-    out = []
-    for r in doc["rows"]:
-        plan = r["coords"]["plan"]
-        out.append((f"sharded_step_collective_bytes_{plan}",
-                    r["metrics"]["collective_bytes_sharded"]))
-        out.append((f"naive_flat_collective_bytes_{plan}",
-                    r["metrics"]["collective_bytes_naive"]))
-    return out
-
-
-register(BenchDef(
-    "kernels_sharded",
-    MatrixConfig.make("kernels_sharded", {"plan": SHARDED_PLANS}),
-    _run_kernels_sharded, _sum_sharded,
-    note="ONE local step of the flat pipeline (flatten -> fused kernel -> "
-         "unflatten) lowered per plan on a (2,4)=('data','model') "
-         "8-host-device mesh; collective bytes parsed from optimized HLO "
-         "(utils/hlo.collective_bytes), 'bytes accessed' from "
-         "xla_cost_properties. sharded arm runs inside shard_map (must be "
-         "0 collective bytes); naive arm is the single global flat view "
-         "the pre-PR launch gate guarded against; tree arm is the old "
-         "fallback baseline."))
-
-
-# --------------------------------------------------------------------------- #
 # serve — production decode path (arch × mode)
 # --------------------------------------------------------------------------- #
 
@@ -1549,7 +1289,7 @@ ALIASES = {
     "async": ("async",),
     "controller": ("async",),     # controller rows live on the arm axis now
     "comm": ("comm",),
-    "kernels": ("kernels", "kernels_fused", "kernels_sharded"),
+    "kernels": ("kernels",),
     "serve": ("serve",),
     "train_lm": ("train_lm",),
 }

@@ -9,13 +9,7 @@ configuration of three orthogonal layers:
   * **ClientLoop**   — H local steps on each of M clients, ``vmap`` over M
     inside a ``lax.scan`` over H (XLA provably emits no cross-client collective
     inside the scan). The per-step update is pluggable: plain SGD, heavy-ball,
-    or locally-scaled via ``preconditioner.py``. With ``use_fused_kernel`` the
-    whole client state rides as per-client flat fp32 buffers and each local
-    step is ONE fused Pallas pass (``kernels.ops.fused_local_step``) for every
-    D̂ rule — bit-identical (fp32) to the tree path (DESIGN.md §7). On
-    model-/FSDP-sharded plans the launch layer supplies a ``ShardedFlatPlan``
-    and the same loop runs per shard via ``shard_map`` (per-device flat
-    blocks; zero flat-buffer collectives).
+    or locally-scaled via ``preconditioner.py``.
   * **SyncStrategy** — the only cross-client traffic per round: full mean,
     weighted partial participation (FedAvg-style client sampling), quantized
     ``sync_dtype`` all-reduce, and a pluggable delta **compression** layer
@@ -58,7 +52,6 @@ from repro.core import controller as CTRL
 from repro.core.controller import ControllerSpec
 from repro.core import preconditioner as PC
 from repro.core.preconditioner import PrecondConfig
-from repro.utils.flatten import FlatLayout, all_float32
 
 
 # --------------------------------------------------------------------------- #
@@ -86,9 +79,6 @@ class ClientLoopSpec:
     stat_source: str = "avg_grad"
     weight_decay: float = 0.0
     grad_clip: float = 0.0         # global-norm clip per local step (0 = off)
-    # flat-buffer fused local step (DESIGN.md §7): ONE Pallas pass per step
-    # for every PrecondConfig kind, bit-identical (fp32) to the tree path
-    use_fused_kernel: bool = False
     reset_momentum: bool = False   # zero m at round start (FedOpt clients)
     local_steps: Optional[tuple] = None  # per-client H_m (None = uniform H)
 
@@ -331,8 +321,7 @@ def method_spec(method: str, *, pc_kind: str = "adam", alpha: float = 1e-2,
                 staleness_weight: str = "constant",
                 server_sync_dtype: str = "", server_sync_k: float = 1.0,
                 controller: Optional[ControllerSpec] = None,
-                personal: tuple = (),
-                use_fused_kernel: bool = False) -> EngineSpec:
+                personal: tuple = ()) -> EngineSpec:
     """Canonical EngineSpec for each named method.
 
     savic       Algorithm 1: locally-scaled heavy-ball clients, plain average.
@@ -348,9 +337,7 @@ def method_spec(method: str, *, pc_kind: str = "adam", alpha: float = 1e-2,
     ``compression`` is either a CompressionSpec or an operator name (then
     ``compression_k`` / ``error_feedback`` fill in the rest) — every method
     gets compressed sync for free, opening the compressed-FedAdam /
-    compressed-Local-Adam scenario family. ``use_fused_kernel`` enables both
-    fused Pallas kernels: the client-loop ``scaled_update`` and (for
-    int8-stochastic) the sync ``quantize_update``.
+    compressed-Local-Adam scenario family.
 
     ``local_steps`` (per-client H_m) and ``asynchrony`` (an AsyncSpec; or the
     ``async_buffer``/``staleness_weight`` shorthand) are engine-level too:
@@ -364,8 +351,7 @@ def method_spec(method: str, *, pc_kind: str = "adam", alpha: float = 1e-2,
     """
     comp = compression if isinstance(compression, CompressionSpec) \
         else CompressionSpec(op=compression, k=compression_k,
-                             error_feedback=error_feedback,
-                             use_fused_kernel=use_fused_kernel)
+                             error_feedback=error_feedback)
     asy = asynchrony if isinstance(asynchrony, AsyncSpec) \
         else AsyncSpec(buffer_rounds=async_buffer, weighting=staleness_weight)
     sync = SyncSpec(participation=participation, sync_dtype=sync_dtype,
@@ -377,7 +363,6 @@ def method_spec(method: str, *, pc_kind: str = "adam", alpha: float = 1e-2,
         spec = engine_spec(
             PrecondConfig(kind=pc_kind, alpha=alpha),
             SavicConfig(gamma=gamma, beta1=beta1, scaling=scaling,
-                        use_fused_kernel=use_fused_kernel,
                         participation=participation, sync_dtype=sync_dtype,
                         compression=comp, local_steps=local_steps,
                         asynchrony=asy))
@@ -386,7 +371,6 @@ def method_spec(method: str, *, pc_kind: str = "adam", alpha: float = 1e-2,
         # FedAvg; heavy-ball local SGD is savic with pc_kind="identity"
         spec = EngineSpec(
             client=ClientLoopSpec(lr=eta_l, momentum=0.0,
-                                  use_fused_kernel=use_fused_kernel,
                                   local_steps=local_steps),
             sync=dataclasses.replace(sync, average_momentum=False),
             server=ServerSpec(kind="average"),
@@ -394,7 +378,6 @@ def method_spec(method: str, *, pc_kind: str = "adam", alpha: float = 1e-2,
     elif method in ("fedadagrad", "fedadam", "fedyogi"):
         spec = EngineSpec(
             client=ClientLoopSpec(lr=eta_l, momentum=0.0, reset_momentum=True,
-                                  use_fused_kernel=use_fused_kernel,
                                   local_steps=local_steps),
             sync=dataclasses.replace(sync, average_momentum=False),
             server=ServerSpec(kind="adaptive", opt=method[3:], eta=eta,
@@ -405,7 +388,6 @@ def method_spec(method: str, *, pc_kind: str = "adam", alpha: float = 1e-2,
     elif method == "local-adam":
         spec = EngineSpec(
             client=ClientLoopSpec(lr=eta_l, momentum=beta1, scaling="local",
-                                  use_fused_kernel=use_fused_kernel,
                                   local_steps=local_steps),
             sync=dataclasses.replace(sync, average_momentum=False),
             server=ServerSpec(kind="adaptive", opt="adam", eta=eta,
@@ -588,8 +570,7 @@ def _objective_grad(objective):
     return grad3
 
 
-def _client_loop(loss_fn, grad_fn, spec: EngineSpec, shard_plan=None,
-                 objective=None):
+def _client_loop(loss_fn, grad_fn, spec: EngineSpec, objective=None):
     """H local steps, vmap-over-M inside a lax.scan over H.
 
     Returns ``run(params_m, mom_m, pstate, micro, keys, h_m=None) ->
@@ -677,183 +658,6 @@ def _client_loop(loss_fn, grad_fn, spec: EngineSpec, shard_plan=None,
             else (micro, keys)
         (params_m, mom_m, pstate, last_grads), losses = jax.lax.scan(
             scan_body, (params_m, mom_m, pstate, grads0), xs)
-        return params_m, mom_m, pstate, last_grads, losses
-
-    if cl.use_fused_kernel:
-        return local_step_one_client, _fused_run(loss_fn, grad_fn, spec, run,
-                                                 shard_plan,
-                                                 objective=objective)
-    return local_step_one_client, run
-
-
-def _local_flat_ops(params_m, local):
-    """Flat ops of the client-parallel fast path: one global ``FlatLayout``
-    (replicated leaves within a client) and the bare fused kernel."""
-    from repro.kernels import ops as kops
-    layout = FlatLayout.for_tree(params_m, batch_dims=1)
-    flat_m = lambda t: layout.flatten(t, batch_dims=1)
-    unflat_m = lambda b: layout.unflatten(b, batch_dims=1)
-    bd = 1 if local else 0
-    flat_d = lambda t: layout.flatten(t, batch_dims=bd)
-    unflat_d = lambda b: layout.unflatten(b, batch_dims=bd)
-    return flat_m, unflat_m, flat_d, unflat_d, kops.fused_local_step
-
-
-def _shard_flat_ops(plan, local):
-    """Flat ops of the shard-mapped fast path (DESIGN.md §7): per-shard flat
-    buffers over the plan's model/FSDP axes, flatten/unflatten and the fused
-    kernel all inside ``shard_map`` (in_specs == out_specs == the storage
-    shardings, so no resharding collective can appear in the local step).
-    The client axis keeps its tree-path semantics: the M dim rides the plan's
-    client entry; per-client ``t`` is sharded over it."""
-    from repro.kernels import ops as kops
-    mesh, lay, cl_entry = plan.mesh, plan.layout, plan.client
-    lead_m = (cl_entry,)
-    lead_d = lead_m if local else ()
-    flat_m = lambda t: lay.flatten(t, mesh, lead=lead_m)
-    unflat_m = lambda b: lay.unflatten(b, mesh, lead=lead_m)
-    flat_d = lambda t: lay.flatten(t, mesh, lead=lead_d)
-    unflat_d = lambda b: lay.unflatten(b, mesh, lead=lead_d)
-    fs_m = lay.flat_spec(lead_m)
-
-    def fused_step(p, m, g, d=None, h=None, t=None, s=None, **kw):
-        update_d = kw.get("update_d", False)
-        operands, in_specs = [p, m, g], [fs_m, fs_m, fs_m]
-        if d is not None:
-            operands.append(d)
-            in_specs.append(fs_m if d.ndim == 2 else lay.flat_spec(()))
-        if h is not None:
-            operands.append(h)
-            in_specs.append(fs_m)
-        if t is not None:
-            operands.append(t)
-            in_specs.append(jax.sharding.PartitionSpec(cl_entry))
-        flags = (d is not None, h is not None, t is not None)
-
-        def body(*args):
-            it = iter(args)
-            p_, m_, g_ = next(it), next(it), next(it)
-            d_ = next(it) if flags[0] else None
-            h_ = next(it) if flags[1] else None
-            t_ = next(it) if flags[2] else None
-            po, mo, do = kops.fused_local_step(p_, m_, g_, d_, h_, t_, s, **kw)
-            return (po, mo, do) if update_d else (po, mo)
-
-        out_specs = (fs_m,) * (3 if update_d else 2)
-        outs = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                             out_specs=out_specs, check_vma=False)(*operands)
-        return outs[0], outs[1], (outs[2] if update_d else None)
-
-    return flat_m, unflat_m, flat_d, unflat_d, fused_step
-
-
-def _fused_run(loss_fn, grad_fn, spec: EngineSpec, tree_run, shard_plan=None,
-               objective=None):
-    """The flat-buffer fused client loop (DESIGN.md §7).
-
-    Same contract as the tree ``run``, but the whole client state rides as
-    per-client flat fp32 buffers ``(M, n_total)`` — flattened here at round
-    start, unflattened only at the sync barrier — and each local step is ONE
-    ``kernels.ops.fused_local_step`` Pallas call covering all M clients and
-    every ``PrecondConfig`` kind: the D̂ update (rule-2 / rule-3 / AdaGrad,
-    const or debias β_t via scalar-prefetched per-client ``t``) fuses with the
-    momentum + scaled parameter update in a single pass.  Bit-identical (fp32)
-    to the tree path for every kind × schedule × clip and all six METHODS
-    (pinned in tests/test_fused_step.py); non-fp32 client state falls back to
-    the tree path (the flat view is an fp32 buffer by contract).
-
-    With ``shard_plan`` (a ``utils.flatten.ShardedFlatPlan``, built by the
-    launch layer from the plan's NamedShardings) the SAME loop runs per model
-    shard: flat buffers become the shard-major per-device blocks of
-    ``ShardFlatLayout`` and flatten / the kernel / unflatten run inside
-    ``shard_map`` over the plan's model/FSDP axes, so the fast path serves
-    model-/FSDP-sharded plans with zero flat-buffer collectives (pinned in
-    tests/test_fused_sharded.py).
-    """
-    cl, pc = spec.client, spec.precond
-    has_d = pc.kind != "identity"
-    # "local" here = D advances inside the loop (global D updates at sync)
-    local = cl.scaling == "local" and has_d
-    # semi-supervised objective: the fused Pallas update is grad-source
-    # agnostic — only the (keyed) grad call changes, so the fast path stays
-    # engaged under every objective (DESIGN.md §12)
-    semi = objective is not None and not objective.is_identity()
-    obj_grad = _objective_grad(objective) if semi else None
-
-    def run(params_m, mom_m, pstate, micro, keys, h_m=None):
-        if not (all_float32(params_m) and all_float32(mom_m)
-                and (not has_d or all_float32(pstate["d"]))):
-            return tree_run(params_m, mom_m, pstate, micro, keys, h_m=h_m)
-        H = jax.tree.leaves(micro)[0].shape[0]
-        M = jax.tree.leaves(params_m)[0].shape[0]
-        masked = _needs_masking(cl, H, M) or h_m is not None
-        bound = h_m if h_m is not None \
-            else (jnp.asarray(cl.local_steps, jnp.int32)
-                  if cl.local_steps is not None else None)
-        flat_m, unflat_m, flat_d, unflat_d, fused_step = \
-            _shard_flat_ops(shard_plan, local) if shard_plan is not None \
-            else _local_flat_ops(params_m, local)
-
-        carry0 = {"p": flat_m(params_m), "m": flat_m(mom_m)}
-        carry0["g"] = jnp.zeros_like(carry0["p"])     # carried sync grads
-        if has_d:
-            carry0["d"] = flat_d(pstate["d"])
-        if local:
-            carry0["t"] = pstate["t"]                 # per-client (M,) i32
-
-        def scan_body(carry, xs):
-            if masked:
-                micro_m, ks, h_idx = xs
-                active = h_idx < bound
-            else:
-                micro_m, ks = xs
-            params_tree = unflat_m(carry["p"])
-            with jax.named_scope("model"):
-                if semi:
-                    losses, grads = jax.vmap(obj_grad)(params_tree, micro_m,
-                                                       ks)
-                else:
-                    losses, grads = jax.vmap(grad_fn)(params_tree, micro_m)
-            with jax.named_scope("local_step"):
-                if cl.grad_clip:
-                    # tree-level clip, exactly as the tree path: the CLIPPED
-                    # grads are what the carry freezes for the sync-time D
-                    # stat
-                    grads = jax.vmap(lambda gt: _clip(gt, cl.grad_clip))(
-                        grads)
-                G = flat_m(grads)
-                hstat = None
-                if local and pc.uses_hutchinson:
-                    stats = jax.vmap(lambda p_, mc, k_: PC.hutchinson_diag(
-                        loss_fn, p_, mc, k_))(params_tree, micro_m, ks)
-                    hstat = flat_m(stats)
-                p_new, m_new, d_new = fused_step(
-                    carry["p"], carry["m"], G, carry.get("d"), hstat,
-                    carry.get("t"), None, gamma=cl.lr, beta1=cl.momentum,
-                    weight_decay=cl.weight_decay, alpha=pc.alpha,
-                    beta2=pc.beta2, kind=pc.kind, clip=pc.clip,
-                    schedule=pc.schedule, update_d=local)
-                new = dict(carry)
-                new["p"], new["m"], new["g"] = p_new, m_new, G
-                if local:
-                    new["d"] = d_new
-                    new["t"] = carry["t"] + 1
-                if masked:
-                    aw = active[:, None]
-                    for k2 in ("p", "m", "g") + (("d",) if local else ()):
-                        new[k2] = jnp.where(aw, new[k2], carry[k2])
-                    if local:
-                        new["t"] = jnp.where(active, new["t"], carry["t"])
-            return new, losses
-
-        xs = (micro, keys, jnp.arange(H, dtype=jnp.int32)) if masked \
-            else (micro, keys)
-        carry, losses = jax.lax.scan(scan_body, carry0, xs)
-        params_m = unflat_m(carry["p"])
-        mom_m = unflat_m(carry["m"])
-        last_grads = unflat_m(carry["g"])
-        if local:
-            pstate = {"d": unflat_d(carry["d"]), "t": carry["t"]}
         return params_m, mom_m, pstate, last_grads, losses
 
     return run
@@ -1275,19 +1079,14 @@ def _adaptive_server_update(spec: ServerSpec, server, x_prev, delta):
 # --------------------------------------------------------------------------- #
 
 
-def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
-                     objective=None, mesh=None):
+def build_round_step(loss_fn: Callable, spec: EngineSpec, objective=None,
+                     mesh=None):
     """loss_fn(params, microbatch) -> scalar.
 
     Returns ``round_step(state, batch, key)`` where each batch leaf is
     (M, H, ...): H microbatches per client per round. Returns (state, metrics).
     Metrics: loss, loss_per_client, client_drift (+ step_norm for adaptive
     servers).
-
-    ``shard_plan`` (optional ``utils.flatten.ShardedFlatPlan``) switches the
-    ``use_fused_kernel`` fast path onto per-shard flat buffers via
-    ``shard_map`` — the launch layer builds it for model-/FSDP-sharded plans
-    (DESIGN.md §7); it is ignored when the client loop is unfused.
 
     ``objective`` (optional ``objectives.ClientObjective``) replaces the
     differentiated client loss with a semi-supervised one (DESIGN.md §12);
@@ -1317,8 +1116,7 @@ def build_round_step(loss_fn: Callable, spec: EngineSpec, shard_plan=None,
             "(per-client D, never synced) or pc kind='identity'.")
     strip = lambda t: strip_personal(personal, t)
     one_pass = _one_pass(spec, mesh)
-    _, client_run = _client_loop(loss_fn, grad_fn, spec, shard_plan,
-                                 objective=objective)
+    client_run = _client_loop(loss_fn, grad_fn, spec, objective=objective)
     ctrl = spec.controller
     if ctrl.enabled:
         # the controller owns the knobs it schedules — conflicting static

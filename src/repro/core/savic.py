@@ -33,9 +33,6 @@ class SavicConfig:
     average_momentum: bool = True      # average momentum buffers at sync
     weight_decay: float = 0.0
     grad_clip: float = 0.0             # global-norm clip per local step (0=off)
-    # flat-buffer fused client loop: one Pallas pass per local step for every
-    # preconditioner kind, bit-identical in fp32 (DESIGN.md §7)
-    use_fused_kernel: bool = False
     # sync compression (beyond-paper; cf. the quantization line of related
     # work [19,20]): all-reduce params/momentum in this dtype ("" = full)
     sync_dtype: str = ""
@@ -61,7 +58,6 @@ def engine_spec(pc_cfg: PrecondConfig, sv_cfg: SavicConfig) -> engine.EngineSpec
             lr=sv_cfg.gamma, momentum=sv_cfg.beta1, scaling=sv_cfg.scaling,
             stat_source=sv_cfg.stat_source, weight_decay=sv_cfg.weight_decay,
             grad_clip=sv_cfg.grad_clip,
-            use_fused_kernel=sv_cfg.use_fused_kernel,
             local_steps=sv_cfg.local_steps),
         sync=engine.SyncSpec(
             participation=sv_cfg.participation, sync_dtype=sv_cfg.sync_dtype,

@@ -58,9 +58,9 @@ def fused_local_step(p, m, g, d=None, h=None, t=None, s=None, *, gamma, beta1,
                      schedule="const", update_d=False):
     """One fused generic-scaling local step on (M, n) flat client buffers.
 
-    The engine's ``use_fused_kernel`` fast path (DESIGN.md §7): fuses the D̂
-    update (rule-2/rule-3/AdaGrad, const or debias β_t) with the momentum and
-    scaled parameter update in ONE ``pallas_call`` covering all M clients.
+    Fuses the D̂ update (rule-2/rule-3/AdaGrad, const or debias β_t) with
+    the momentum and scaled parameter update in ONE ``pallas_call`` covering
+    all M rows. No engine path calls it yet (DESIGN.md §7).
     ``d`` is (M, n) for local scaling, (n,) for global, None for identity;
     ``h`` is the external (Hutchinson) stat; ``t``/``s`` are per-client step
     counters / grad-clip scales (scalar prefetch). Returns (p', m', d'|None).

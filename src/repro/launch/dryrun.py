@@ -37,8 +37,7 @@ from repro.utils.hlo_cost import xla_cost_properties
 def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             mode: str = "auto", method: str = "savic", compression=None,
             het_model=None, het_seed: int = 0, het_sigma: float = 0.6,
-            asynchrony=None, controller=None, use_fused_kernel: bool = False,
-            objective=None, labeled_frac: float = 1.0, personal=None,
+            asynchrony=None, controller=None, objective=None, labeled_frac: float = 1.0, personal=None,
             out_dir: str = "results/dryrun",
             save: bool = True, call=None, tag: str = "", verbose=True):
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -54,8 +53,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
                        het_seed=het_seed, het_sigma=het_sigma,
                        asynchrony=asynchrony, controller=controller,
                        objective=objective, labeled_frac=labeled_frac,
-                       personal=personal,
-                       use_fused_kernel=use_fused_kernel, call=call) \
+                       personal=personal, call=call) \
         if shape.kind == "train" else build_step(arch, shape_name, mesh,
                                                  call=call)
     with mesh:
@@ -119,18 +117,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         # heterogeneity & staleness (DESIGN.md §5): the H_m vector is a spec
         # constant (baked into the program); the buffer is server state
         rec["asynchrony"] = _dc.asdict(spec.sync.asynchrony)
-        if "flat_layout" in built.meta:
-            # fused flat-buffer client loop (DESIGN.md §7): the in-round
-            # flat-view layout the scan runs over
-            rec["flat_layout"] = built.meta["flat_layout"]
-        if "flat_layout_sharded" in built.meta:
-            # shard-mapped fused path (model-/FSDP-sharded plans): the
-            # per-shard flat layout — each device's (M, n_local) block
-            rec["flat_layout_sharded"] = built.meta["flat_layout_sharded"]
-        if "fused_kernel_fallback" in built.meta:
-            # only genuinely ineligible builds fall back now (non-fp32
-            # client state); sharded plans take the shard_map fast path
-            rec["fused_kernel_fallback"] = built.meta["fused_kernel_fallback"]
         if "objective" in built.meta:
             # client objective & personalization (DESIGN.md §12): the kind,
             # labeled fraction and client-resident leaf mask the program was
@@ -208,9 +194,6 @@ def main():
                     help="enable the adaptive communication-budget controller "
                          "(round-addressable H_m/k/b_eff knobs; artifact "
                          "records the spec + initial knob values)")
-    ap.add_argument("--use-fused-kernel", action="store_true",
-                    help="flat-buffer fused client loop (one Pallas pass per "
-                         "local step; artifact records the flat-view layout)")
     ap.add_argument("--objective", default="supervised",
                     help="client objective for train shapes "
                          "(supervised|consistency|pseudo-label)")
@@ -250,7 +233,6 @@ def main():
                         asynchrony=asy, controller=ctrl,
                         objective=obj, labeled_frac=args.labeled_frac,
                         personal=personal,
-                        use_fused_kernel=args.use_fused_kernel,
                         out_dir=args.out, tag=args.tag)
             except Exception as e:  # noqa
                 failures.append((arch, shape, repr(e)))
@@ -265,8 +247,7 @@ def main():
             method=args.method, compression=comp, het_model=het,
             het_seed=args.het_seed, het_sigma=args.het_sigma, asynchrony=asy,
             controller=ctrl, objective=obj, labeled_frac=args.labeled_frac,
-            personal=personal, use_fused_kernel=args.use_fused_kernel,
-            out_dir=args.out, tag=args.tag)
+            personal=personal, out_dir=args.out, tag=args.tag)
 
 
 if __name__ == "__main__":

@@ -80,7 +80,7 @@ def build_train_step(arch: str, shape: ShapeConfig, mesh, *, mode: str = "auto",
                      objective: Optional[objectives.ObjectiveSpec] = None,
                      labeled_frac: float = 1.0,
                      personal: Optional[tuple] = None,
-                     use_fused_kernel: bool = False, seed: int = 0,
+                     seed: int = 0,
                      n_layers: Optional[int] = None):
     cfg = get_config(arch, reduced=reduced)
     if n_layers:                   # depth cut to one chip; widths kept
@@ -160,12 +160,6 @@ def build_train_step(arch: str, shape: ShapeConfig, mesh, *, mode: str = "auto",
     if asynchrony is not None:
         spec = dataclasses.replace(
             spec, sync=dataclasses.replace(spec.sync, asynchrony=asynchrony))
-    if use_fused_kernel:
-        # engine-level knob: the flat-buffer fused client loop (DESIGN.md §7)
-        # is valid for every method/PrecondConfig kind
-        spec = dataclasses.replace(
-            spec, client=dataclasses.replace(spec.client,
-                                             use_fused_kernel=True))
     if personal:
         # client-resident leaves (DESIGN.md §12): engine-level knob like
         # compression/asynchrony — applies to every method / engine_spec
@@ -194,44 +188,7 @@ def build_train_step(arch: str, shape: ShapeConfig, mesh, *, mode: str = "auto",
     batch_shape = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct((M, H) + s.shape, s.dtype), micro)
 
-    shard_plan = None
-    if spec.client.use_fused_kernel:
-        bad = _fused_non_fp32(state_shape, spec)
-        if bad:
-            # genuinely ineligible: the flat view is an fp32 buffer by
-            # contract — take the (identical-semantics) tree path
-            spec = dataclasses.replace(
-                spec, client=dataclasses.replace(spec.client,
-                                                 use_fused_kernel=False))
-            het_meta["fused_kernel_fallback"] = \
-                f"non-fp32 client state ({bad}; flat view is fp32 by contract)"
-        elif _ax(mesh, plan.model) > 1 or plan.fsdp_params:
-            # model-/FSDP-sharded plan: the single global flat view would make
-            # GSPMD reshard the whole client state EVERY local step (measured
-            # ~4e5× collective-byte blowup on the 16×16 mesh) — instead run
-            # the fused step PER SHARD via shard_map (DESIGN.md §7): each
-            # device flattens only its local leaf shards; state pytree,
-            # shardings and donation below stay the tree path's
-            from repro.utils.flatten import ShardedFlatPlan
-            shard_axes = tuple(plan.model) + (tuple(plan.batch)
-                                              if plan.fsdp_params else ())
-            params_one = jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
-                state_shape["params"])
-            pspecs_one = params_pspecs(cfg, params_one, mesh, plan,
-                                       client_dim=False)
-            shard_plan = ShardedFlatPlan.build(
-                mesh, params_one, pspecs_one, shard_axes,
-                client=tuple(plan.client) if plan.client else None)
-            het_meta["flat_layout_sharded"] = shard_plan.layout.describe()
-        else:
-            # client-parallel plan (replicated leaves within a client): the
-            # original single flat view; layout recorded for dry-run artifacts
-            from repro.utils.flatten import FlatLayout
-            het_meta["flat_layout"] = FlatLayout.for_tree(
-                state_shape["params"], batch_dims=1).describe()
     round_step = engine.build_round_step(model.loss, spec,
-                                         shard_plan=shard_plan,
                                          objective=client_objective,
                                          mesh=mesh)
 
@@ -263,24 +220,6 @@ def build_train_step(arch: str, shape: ShapeConfig, mesh, *, mode: str = "auto",
               "b_client": b_client, "cfg": cfg, "plan": plan,
               "engine_spec": spec, "head": head, **het_meta},
     )
-
-
-def _fused_non_fp32(state_shape, spec: engine.EngineSpec) -> str:
-    """Name the first non-fp32 fused-client-state leaf group, or "".
-
-    Mirrors the engine's trace-time ``all_float32`` gate (DESIGN.md §7) so the
-    launch layer can record WHY a build fell back to the tree path — the meta
-    contract asserted in tests/test_system.py.
-    """
-    from repro.utils.flatten import all_float32
-    for name in ("params", "mom"):
-        if not all_float32(state_shape[name]):
-            return name
-    if "d" in state_shape["precond"] \
-            and spec.precond.kind != "identity" \
-            and not all_float32(state_shape["precond"]["d"]):
-        return "precond.d"
-    return ""
 
 
 def _engine_state_spec(cfg, state_shape, mesh, plan, spec: engine.EngineSpec):

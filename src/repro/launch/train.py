@@ -7,9 +7,9 @@ checkpoint format (DESIGN.md §9):
   round step; runs anywhere, used by the CPU examples and tests.
 * ``--mesh production|production-2pod|debug`` — the launch-layer path:
   ``steps.build_train_step`` builds the jitted step with the mesh plan's
-  shardings and donation (paper / paper_fsdp / plain modes, shard-mapped
-  fused local step on sharded plans, DESIGN.md §2/§7). The plan fixes the
-  client count M (e.g. 16 on the 16×16 production mesh in paper mode);
+  shardings and donation (paper / paper_fsdp / plain modes, DESIGN.md
+  §2). The plan fixes the client count M (e.g. 16 on the 16×16
+  production mesh in paper mode);
   ``--clients`` applies to the single-host path only. Production meshes
   on CPU need ``XLA_FLAGS=--xla_force_host_platform_device_count=512``
   set before jax initializes (see launch/dryrun.py).
@@ -34,7 +34,7 @@ Examples:
       --method local-adam --rounds 5 --clients 2 --batch 2 --seq 64
   XLA_FLAGS=--xla_force_host_platform_device_count=512 \
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
-      --mesh production --batch 16 --seq 4096 --use-fused-kernel
+      --mesh production --batch 16 --seq 4096
 """
 from __future__ import annotations
 
@@ -162,13 +162,6 @@ def _parser():
                          "resident (never synced/served; e.g. 'final_norm'). "
                          "Personalizing under a GLOBAL non-identity D is "
                          "rejected at build time (DESIGN.md §12)")
-    ap.add_argument("--use-fused-kernel", action="store_true",
-                    help="flat-buffer fused client loop: one Pallas pass per "
-                         "local step, every preconditioner kind (DESIGN.md "
-                         "§7; bit-identical in fp32). Mesh launches run it "
-                         "per-shard via shard_map on model-/FSDP-sharded "
-                         "plans; the single-host path uses the unsharded "
-                         "flat view")
     ap.add_argument("--profile-dir", default="",
                     help="write a profiler trace of the PROFILE_ROUNDS rounds "
                          "after the compile round to this directory: each "
@@ -221,7 +214,6 @@ def _resolve_spec(args, n_clients):
                          scaling=args.scaling,
                          participation=args.participation,
                          sync_dtype=args.sync_dtype,
-                         use_fused_kernel=args.use_fused_kernel,
                          compression=comp, local_steps=local_steps,
                          asynchrony=asy)
         spec = savic.engine_spec(pc, sv)
@@ -232,8 +224,7 @@ def _resolve_spec(args, n_clients):
             tau=args.tau, server_beta1=args.server_beta1,
             participation=args.participation,
             sync_dtype=args.sync_dtype, compression=comp,
-            local_steps=local_steps, asynchrony=asy,
-            use_fused_kernel=args.use_fused_kernel)
+            local_steps=local_steps, asynchrony=asy)
     if ctrl is not None:
         import dataclasses as _dc
         spec = _dc.replace(spec, controller=ctrl)
@@ -329,12 +320,6 @@ def main(argv=None):
             reduced=args.reduced, h_local=args.h_local, call=call,
             objective=_objective_spec(args), labeled_frac=args.labeled_frac,
             seed=args.seed + 1, n_layers=args.layers or None)
-        spec = built.meta["engine_spec"]   # fused fallback may have applied
-        if "fused_kernel_fallback" in built.meta:
-            log.setup["fused_kernel_fallback"] = \
-                built.meta["fused_kernel_fallback"]
-            print(f"[train] fused kernel fallback: "
-                  f"{built.meta['fused_kernel_fallback']}", flush=True)
         state_shardings, batch_shardings = built.in_shardings
         jitted = jax.jit(built.fn, in_shardings=built.in_shardings,
                          out_shardings=built.out_shardings,

@@ -7,6 +7,9 @@ METHODS. Same pattern as _reference_savic.py / _reference_fedopt.py: the
 reference runs in-session so the comparison is exact on this backend.
 
 Do not edit (except this header); regenerate by snapshotting engine.py.
+One edit since: ``method_spec("savic")`` builds its spec from this module's
+own classes, with the values core/savic.py's ``engine_spec`` gave them at
+the snapshot, instead of calling the live ``engine_spec``.
 """
 from __future__ import annotations
 
@@ -107,14 +110,15 @@ def method_spec(method: str, *, pc_kind: str = "adam", alpha: float = 1e-2,
     """
     sync = SyncSpec(participation=participation, sync_dtype=sync_dtype)
     if method == "savic":
-        # one source of truth for the SAVIC composition: SavicConfig ->
-        # engine_spec in core/savic.py (lazy import; savic imports engine)
-        from repro.core.savic import SavicConfig, engine_spec
-        return engine_spec(
-            PrecondConfig(kind=pc_kind, alpha=alpha),
-            SavicConfig(gamma=gamma, beta1=beta1, scaling=scaling,
-                        use_fused_kernel=use_fused_kernel,
-                        participation=participation, sync_dtype=sync_dtype))
+        # the SAVIC composition core/savic.py:engine_spec made at this
+        # snapshot, in this module's own spec classes (so the reference
+        # builds no spec of the code under test)
+        return EngineSpec(
+            client=ClientLoopSpec(lr=gamma, momentum=beta1, scaling=scaling,
+                                  use_fused_kernel=use_fused_kernel),
+            sync=sync,
+            server=ServerSpec(kind="average"),
+            precond=PrecondConfig(kind=pc_kind, alpha=alpha))
     if method == "fedavg":
         # plain Local SGD clients (no momentum), plain average — textbook
         # FedAvg; heavy-ball local SGD is savic with pc_kind="identity"

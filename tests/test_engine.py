@@ -1,6 +1,8 @@
 """Round-engine tests: regression against the pre-refactor monoliths,
 SyncStrategy coverage (participation / quantized sync), adaptive-server
 methods end-to-end, and the build_train_step method selector."""
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -66,14 +68,35 @@ SAVIC_REGRESSION_CASES = {
         dict(gamma=0.03, beta1=0.0, participation=0.5,
              sync_dtype="bfloat16")),
 }
+# the scaled local step's whole matrix: every PrecondConfig kind × β_t
+# schedule × rule-4 clip × scaling mode. adahessian × debias × local feeds a
+# negative v⊙Hv stat into the √d magnitude and goes NaN in both programs;
+# those cases pin equal NaN positions.
+SAVIC_REGRESSION_CASES.update({
+    f"{kind}-{schedule}-{clip}-{scaling}": (
+        PrecondConfig(kind=kind, alpha=1e-2, beta_schedule=schedule,
+                      clip=clip),
+        dict(gamma=0.01, beta1=0.9, scaling=scaling))
+    for kind, schedule, clip, scaling in itertools.product(
+        ("adam", "rmsprop", "adagrad", "oasis", "adahessian"),
+        ("const", "debias"), ("max", "add"), ("global", "local"))})
+# grad clip and weight decay composed with the local step
+SAVIC_REGRESSION_CASES.update({
+    f"adam-local-{name}": (
+        PrecondConfig(kind="adam", alpha=1e-2),
+        dict(gamma=0.01, beta1=0.9, scaling="local", **extra))
+    for name, extra in (("clip", dict(grad_clip=0.5)),
+                        ("wd", dict(weight_decay=0.01)),
+                        ("clip-wd", dict(grad_clip=0.3, weight_decay=0.05)))})
 
 
 @pytest.mark.parametrize("case", list(SAVIC_REGRESSION_CASES))
 def test_savic_engine_matches_prerefactor(problem, case):
     """The engine emits the same program the monolithic savic.py did:
-    trajectories agree bit-for-bit (asserted to fp32 tolerance) for every
-    layer combination — scaling kind, momentum, stat source, participation,
-    quantized sync."""
+    trajectories agree bitwise (NaN positions included) for every layer
+    combination — preconditioner kind, β_t schedule, clip, scaling mode,
+    momentum, stat source, participation, quantized sync, grad clip and
+    weight decay."""
     pc, sv_kw = SAVIC_REGRESSION_CASES[case]
     loss = _quad_loss(problem)
     sv_new = SavicConfig(**sv_kw)
@@ -85,14 +108,14 @@ def test_savic_engine_matches_prerefactor(problem, case):
     st_old = ref_savic.init_state(jax.random.PRNGKey(0), init, pc, sv_old, 4)
     for st_n, st_o, met_n, met_o in _trajectories(problem, step_new, st_new,
                                                   step_old, st_old):
-        np.testing.assert_allclose(np.asarray(st_n["params"]["x"]),
-                                   np.asarray(st_o["params"]["x"]),
-                                   rtol=1e-6, atol=1e-7)
-        np.testing.assert_allclose(float(met_n["loss"]), float(met_o["loss"]),
-                                   rtol=1e-6)
-        np.testing.assert_allclose(float(met_n["client_drift"]),
-                                   float(met_o["client_drift"]), rtol=1e-5,
-                                   atol=1e-9)
+        for name in ("params", "mom", "precond"):
+            jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b), err_msg=name),
+                st_n[name], st_o[name])
+        for name in ("loss", "client_drift"):
+            np.testing.assert_array_equal(np.asarray(met_n[name]),
+                                          np.asarray(met_o[name]),
+                                          err_msg=name)
 
 
 @pytest.mark.parametrize("server_opt", ["adagrad", "adam", "yogi"])

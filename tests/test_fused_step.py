@@ -1,29 +1,17 @@
-"""Flat-buffer fused local step — the kernel-differential suite (DESIGN.md §7).
+"""The fused local-step kernel against its pure-jnp oracle.
 
-Locks down the ``use_fused_kernel`` fast path of the round engine:
+``kernels/scaled_update.py:fused_step_flat`` (through
+``kernels.ops.fused_local_step``) updates D̂, momentum and parameters of
+``(M, n)`` blocks in one Pallas pass. No engine path calls it today; it is
+kept as the candidate for a per-leaf scaled local step (DESIGN.md §7).
 
-  * differential pinning: the fused flat-buffer client loop is BIT-IDENTICAL
-    (fp32) to the unfused tree path for all six METHODS, and to the verbatim
-    pre-PR engine snapshot (tests/_reference_engine.py);
-  * the kernel family itself (``fused_step_flat``) matches the pure-jnp
-    oracle (``ref.fused_step_ref``) bitwise for every PrecondConfig kind ×
-    β_t schedule × rule-4 clip, local/global/identity D, external (Hutchinson)
-    and in-kernel grad² stats — including negative rule-3 (OASIS) D state;
-  * the engine-level kind × schedule × clip × scaling matrix (tier-2 @slow;
-    a representative slice stays in tier-1) plus grad-clip / weight-decay /
-    heterogeneous-H_m compositions;
-  * flatten/unflatten round-trips on ragged leaf shapes (deterministic +
-    hypothesis via the _hypothesis_compat shim);
+  * the kernel matches ``ref.fused_step_ref`` for every PrecondConfig kind ×
+    β_t schedule × rule-4 clip, local/global/identity D, external
+    (Hutchinson) and in-kernel grad² stats — including negative rule-3
+    (OASIS) D state;
   * the per-rule padding contract at n % BLOCK ∈ {0, 1, BLOCK−1} — sliced
-    outputs bitwise, padded lanes never poison them;
-  * non-fp32 client state falls back to the (identical) tree path;
-  * launch layer: build_train_step threads use_fused_kernel and records the
-    flat-view layout in BuiltStep meta without changing shardings.
-
-NaN notes: adahessian feeds the raw (possibly negative) v⊙Hv stat into the
-rule-2 √d magnitude, so some configs NaN by design; bitwise comparisons use
-assert_array_equal (NaN == NaN), pinning that fused and unfused diverge
-identically.
+    outputs bitwise, padded lanes never poison them (deterministic and
+    hypothesis via the _hypothesis_compat shim).
 """
 import itertools
 
@@ -32,190 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import _reference_engine as ref_engine
 from _hypothesis_compat import given, settings, st
-from repro.core import engine, savic
-from repro.core.preconditioner import PrecondConfig
-from repro.data import QuadraticLoader, QuadraticProblem
 from repro.kernels import ops, ref
 from repro.kernels import scaled_update as su
-from repro.utils.flatten import FlatLayout
-
-MS_KW = dict(gamma=0.01, alpha=1e-2, eta_l=0.01, eta=0.05)
-
-
-@pytest.fixture(scope="module")
-def problem():
-    return QuadraticProblem.make(d=24, M=4, mu=0.5, L=5.0, sigma=0.3, seed=0)
-
-
-def _quad_loss(problem):
-    Q = jnp.asarray(problem.Q, jnp.float32)
-    b = jnp.asarray(problem.b, jnp.float32)
-
-    def loss(params, micro):
-        x = params["x"]
-        return 0.5 * (x - b[0]) @ Q[0] @ (x - b[0]) + micro["z"] @ x
-
-    return loss
-
-
-def _run(problem, build_round_step, init_state, spec, rounds=3, H=3, seed=0,
-         n_clients=4, dtype=jnp.float32):
-    loss = _quad_loss(problem)
-    step = jax.jit(build_round_step(loss, spec))
-    state = init_state(jax.random.PRNGKey(0),
-                       lambda k: {"x": jnp.zeros(24, dtype)}, spec, n_clients)
-    loader = QuadraticLoader(problem, seed=seed)
-    key = jax.random.PRNGKey(seed + 1)
-    for _ in range(rounds):
-        key, k = jax.random.split(key)
-        state, met = step(state, jax.tree.map(jnp.asarray,
-                                              loader.round_batch(H)), k)
-    return state, met
-
-
-def _assert_state_bitwise(st_a, st_b):
-    """Bitwise trajectory equality (NaN-positions included)."""
-    np.testing.assert_array_equal(np.asarray(st_a["params"]["x"]),
-                                  np.asarray(st_b["params"]["x"]))
-    np.testing.assert_array_equal(np.asarray(st_a["mom"]["x"]),
-                                  np.asarray(st_b["mom"]["x"]))
-    if "d" in st_b["precond"]:
-        np.testing.assert_array_equal(np.asarray(st_a["precond"]["d"]["x"]),
-                                      np.asarray(st_b["precond"]["d"]["x"]))
-        np.testing.assert_array_equal(np.asarray(st_a["precond"]["t"]),
-                                      np.asarray(st_b["precond"]["t"]))
-    if "server" in st_b:
-        np.testing.assert_array_equal(np.asarray(st_a["server"]["v"]["x"]),
-                                      np.asarray(st_b["server"]["v"]["x"]))
-        np.testing.assert_array_equal(np.asarray(st_a["server"]["m"]["x"]),
-                                      np.asarray(st_b["server"]["m"]["x"]))
-
-
-# --------------------------------------------------------------------------- #
-# differential: fused == unfused == pre-PR reference, all six METHODS
-# --------------------------------------------------------------------------- #
-
-
-@pytest.mark.parametrize("method", engine.METHODS)
-def test_fused_bit_identical_all_methods(problem, method):
-    """The flat-buffer fused client loop emits the same trajectory as the
-    unfused tree path AND the verbatim pre-PR engine snapshot — bitwise."""
-    spec_f = engine.method_spec(method, **MS_KW, use_fused_kernel=True)
-    assert spec_f.client.use_fused_kernel
-    spec_u = engine.method_spec(method, **MS_KW)
-    spec_r = ref_engine.method_spec(method, **MS_KW)
-    st_f, met_f = _run(problem, engine.build_round_step, engine.init_state,
-                       spec_f)
-    st_u, met_u = _run(problem, engine.build_round_step, engine.init_state,
-                       spec_u)
-    st_r, met_r = _run(problem, ref_engine.build_round_step,
-                       ref_engine.init_state, spec_r)
-    _assert_state_bitwise(st_f, st_u)
-    _assert_state_bitwise(st_f, st_r)
-    assert float(met_f["loss"]) == float(met_u["loss"]) == float(met_r["loss"])
-
-
-FAST_ENGINE_CASES = [
-    # a representative slice of the kind × schedule × clip × scaling matrix
-    # stays in tier-1 (the full sweep is the @slow test below)
-    dict(kind="oasis", scaling="local"),              # rule-3 + Hutchinson
-    dict(kind="adahessian", scaling="local", beta_schedule="debias"),
-    dict(kind="adagrad", scaling="local"),            # accumulate limit
-    dict(kind="rmsprop", scaling="global", clip="add"),
-    dict(kind="adam", scaling="local", clip="add", beta_schedule="const"),
-]
-
-
-@pytest.mark.parametrize("case", FAST_ENGINE_CASES,
-                         ids=lambda c: "-".join(str(v) for v in c.values()))
-def test_fused_bit_identical_representative_kinds(problem, case):
-    pcf = {k: v for k, v in case.items()
-           if k in ("kind", "clip", "beta_schedule")}
-    pc = PrecondConfig(alpha=1e-2, **pcf)
-    mk = lambda fused: savic.engine_spec(pc, savic.SavicConfig(
-        gamma=0.01, beta1=0.9, scaling=case["scaling"],
-        use_fused_kernel=fused))
-    st_f, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(True))
-    st_u, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(False))
-    _assert_state_bitwise(st_f, st_u)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("kind,schedule,clip,scaling", list(itertools.product(
-    ("adam", "rmsprop", "adagrad", "oasis", "adahessian"),
-    ("const", "debias"), ("max", "add"), ("global", "local"))))
-def test_fused_bit_identical_full_matrix(problem, kind, schedule, clip,
-                                         scaling):
-    """Acceptance sweep: every PrecondConfig kind × β_t schedule × rule-4
-    clip × scaling mode, fused vs unfused, bitwise (tier-2)."""
-    pc = PrecondConfig(kind=kind, alpha=1e-2, beta_schedule=schedule,
-                       clip=clip)
-    mk = lambda fused: savic.engine_spec(pc, savic.SavicConfig(
-        gamma=0.01, beta1=0.9, scaling=scaling, use_fused_kernel=fused))
-    st_f, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(True), rounds=2)
-    st_u, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(False), rounds=2)
-    _assert_state_bitwise(st_f, st_u)
-
-
-@pytest.mark.parametrize("extra", [
-    dict(grad_clip=0.5),
-    dict(weight_decay=0.01),
-    dict(local_steps=(1, 3, 2, 3)),
-    dict(grad_clip=0.3, weight_decay=0.05, local_steps=(2, 1, 3, 3)),
-], ids=["clip", "wd", "hm", "clip-wd-hm"])
-def test_fused_bit_identical_compositions(problem, extra):
-    """grad-clip (tree-order norm), weight decay, and heterogeneous-H_m
-    masking all compose with the fused path bitwise: clipped grads freeze
-    into the sync-stat carry exactly as in the tree path, and frozen clients
-    keep their step-H_m flat state."""
-    pc = PrecondConfig(kind="adam", alpha=1e-2)
-    mk = lambda fused: savic.engine_spec(pc, savic.SavicConfig(
-        gamma=0.01, beta1=0.9, scaling="local", use_fused_kernel=fused,
-        **extra))
-    st_f, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(True))
-    st_u, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(False))
-    _assert_state_bitwise(st_f, st_u)
-
-
-def test_fused_masked_hutchinson(problem):
-    """H_m masking freezes the per-client D and t of a Hutchinson kind at
-    exactly the client's budget — fused vs unfused bitwise."""
-    pc = PrecondConfig(kind="oasis", alpha=1e-2)
-    mk = lambda fused: savic.engine_spec(pc, savic.SavicConfig(
-        gamma=0.01, beta1=0.9, scaling="local", use_fused_kernel=fused,
-        local_steps=(2, 3, 1, 3)))
-    st_f, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(True))
-    st_u, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(False))
-    _assert_state_bitwise(st_f, st_u)
-    # frozen clients really did stop advancing t
-    assert st_f["precond"]["t"].shape == (4,)
-    np.testing.assert_array_equal(np.asarray(st_f["precond"]["t"]),
-                                  3 * np.asarray([2, 3, 1, 3]))
-
-
-def test_non_fp32_state_falls_back_to_tree_path(problem):
-    """The flat view is an fp32 buffer by contract: bf16 client state takes
-    the (bit-identical-to-itself) tree path instead of silently upcasting."""
-    pc = PrecondConfig(kind="adam", alpha=1e-2)
-    mk = lambda fused: savic.engine_spec(pc, savic.SavicConfig(
-        gamma=0.01, beta1=0.9, scaling="global", use_fused_kernel=fused))
-    st_f, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(True), dtype=jnp.bfloat16)
-    st_u, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(False), dtype=jnp.bfloat16)
-    assert st_f["params"]["x"].dtype == jnp.bfloat16
-    np.testing.assert_array_equal(np.asarray(st_f["params"]["x"], np.float32),
-                                  np.asarray(st_u["params"]["x"], np.float32))
 
 
 # --------------------------------------------------------------------------- #
@@ -246,9 +53,8 @@ def test_kernel_matrix_local_vs_oracle(kind, schedule, clip):
     Tolerance note: this compares two SEPARATELY compiled programs (the
     interpret-mode grid loop vs a plain jit of the oracle), where XLA:CPU may
     contract multiply-adds into FMAs differently — a 1-ulp effect.  The
-    bit-exactness contract that matters is same-program-shape: engine fused
-    vs unfused above are bitwise, and the padding tests below pin the kernel
-    bitwise against the oracle where contraction agrees."""
+    padding tests below pin the kernel bitwise against the oracle where
+    contraction agrees."""
     p, m, g, d_signed, h, t, s = _kernel_buffers()
     hutch = kind in ("oasis", "adahessian")
     d = d_signed if kind == "oasis" else jnp.abs(d_signed)
@@ -351,55 +157,6 @@ def test_scaled_update_flat_padding_boundaries(n, squared):
     pr, mr = jax.jit(
         lambda *a: ref.scaled_update_ref(*a, **kw))(p, m, g, d)
     np.testing.assert_array_equal(np.asarray(po), np.asarray(pr))
-    np.testing.assert_array_equal(np.asarray(mo), np.asarray(mr))
-
-
-# --------------------------------------------------------------------------- #
-# flatten/unflatten round-trips on ragged leaves
-# --------------------------------------------------------------------------- #
-
-
-RAGGED_SHAPES = [
-    {"a": (3,), "b": (2, 5), "c": ()},
-    {"w1": (17, 33), "b1": (33,), "w2": (33, 7), "b2": (7,)},
-    {"x": (1,)},
-]
-
-
-@pytest.mark.parametrize("shapes", RAGGED_SHAPES,
-                         ids=["mixed", "mlp", "single"])
-@pytest.mark.parametrize("batch_dims", [0, 1])
-def test_flat_layout_round_trip(shapes, batch_dims):
-    k = jax.random.key(0)
-    lead = (4,) if batch_dims else ()
-    tree = {name: jax.random.normal(jax.random.fold_in(k, i), lead + shp)
-            for i, (name, shp) in enumerate(shapes.items())}
-    layout = FlatLayout.for_tree(tree, batch_dims=batch_dims)
-    buf = layout.flatten(tree, batch_dims=batch_dims)
-    assert buf.shape == lead + (layout.n_total,)
-    assert layout.n_total == sum(
-        int(np.prod(s)) if s else 1 for s in shapes.values())
-    back = layout.unflatten(buf, batch_dims=batch_dims)
-    assert jax.tree.structure(back) == jax.tree.structure(tree)
-    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    desc = layout.describe()
-    assert desc["n_total"] == layout.n_total
-    assert [l["path"] for l in desc["leaves"]] == list(layout.paths)
-
-
-@given(st.lists(st.lists(st.integers(min_value=1, max_value=5), min_size=0,
-                         max_size=3), min_size=1, max_size=6),
-       st.integers(min_value=0, max_value=99))
-@settings(max_examples=25, deadline=None)
-def test_flat_layout_round_trip_property(shapes, seed):
-    k = jax.random.key(seed)
-    tree = {f"l{i}": jax.random.normal(jax.random.fold_in(k, i), tuple(shp))
-            for i, shp in enumerate(shapes)}
-    layout = FlatLayout.for_tree(tree)
-    back = layout.unflatten(layout.flatten(tree))
-    for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @given(st.integers(min_value=1, max_value=300), st.integers(0, 50))
@@ -420,69 +177,3 @@ def test_fused_padding_property(n, seed):
         lambda *a: ref.fused_step_ref(*a, **kw))(p, m, g, d, None, None, None)
     np.testing.assert_array_equal(np.asarray(po), np.asarray(pr))
     np.testing.assert_array_equal(np.asarray(do), np.asarray(dr))
-
-
-# --------------------------------------------------------------------------- #
-# launch layer: flat-view layout in BuiltStep meta, shardings unchanged
-# --------------------------------------------------------------------------- #
-
-
-def test_build_train_step_records_flat_layout():
-    from jax.sharding import Mesh
-
-    from repro.configs import ShapeConfig
-    from repro.launch.steps import build_train_step
-
-    dev = np.array(jax.devices("cpu")[:1]).reshape(1, 1)
-    mesh = Mesh(dev, ("data", "model"))
-    shape = ShapeConfig("tiny_train", 32, 2, "train")
-    built_f = build_train_step("qwen2-0.5b", shape, mesh, method="local-adam",
-                               reduced=True, h_local=2, use_fused_kernel=True)
-    built_u = build_train_step("qwen2-0.5b", shape, mesh, method="local-adam",
-                               reduced=True, h_local=2)
-    assert built_f.meta["engine_spec"].client.use_fused_kernel
-    lay = built_f.meta["flat_layout"]
-    state_shape = built_f.args[0]
-    n_params = sum(int(np.prod(s.shape[1:]))
-                   for s in jax.tree.leaves(state_shape["params"]))
-    assert lay["n_total"] == n_params
-    assert "flat_layout" not in built_u.meta
-    # the flat view is an in-round representation: state pytree, shardings
-    # and donation are those of the tree path, unchanged
-    assert jax.tree.structure(built_f.args[0]) \
-        == jax.tree.structure(built_u.args[0])
-    sf = jax.tree.map(str, built_f.in_shardings[0])
-    uf = jax.tree.map(str, built_u.in_shardings[0])
-    assert sf == uf
-    assert built_f.donate == built_u.donate == (0,)
-
-
-def test_build_train_step_sharded_params_take_shard_mapped_path():
-    """The launch layer no longer strips the fused path on sharded plans
-    (DESIGN.md §7): a plan that shards params within a client (here
-    plain-mode FSDP) keeps ``use_fused_kernel`` and runs the fused step per
-    shard via shard_map, recording the per-shard flat layout instead of a
-    fallback (the full multi-device contract lives in
-    tests/test_fused_sharded.py)."""
-    from jax.sharding import Mesh
-
-    from repro.configs import ShapeConfig
-    from repro.launch.steps import build_train_step
-
-    dev = np.array(jax.devices("cpu")[:1]).reshape(1, 1)
-    mesh = Mesh(dev, ("data", "model"))
-    shape = ShapeConfig("tiny_train", 32, 2, "train")
-    built = build_train_step("qwen2-0.5b", shape, mesh, method="fedadam",
-                             mode="plain", reduced=True, h_local=2,
-                             use_fused_kernel=True)
-    assert built.meta["engine_spec"].client.use_fused_kernel
-    assert "fused_kernel_fallback" not in built.meta
-    assert "flat_layout" not in built.meta
-    lay = built.meta["flat_layout_sharded"]
-    # plain mode on this 1x1 mesh: FSDP over ('model', 'data') extents 1 —
-    # every leaf degenerates to one replicated shard block
-    assert lay["n_shards"] == 1
-    state_shape = built.args[0]
-    n_params = sum(int(np.prod(s.shape[1:]))
-                   for s in jax.tree.leaves(state_shape["params"]))
-    assert lay["n_flat"] == n_params

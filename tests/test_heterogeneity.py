@@ -18,6 +18,8 @@ Locks down ClientLoopSpec.local_steps + AsyncSpec (DESIGN.md §5):
   * spec validation — deterministic versions plus hypothesis variants via
     _hypothesis_compat.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -103,37 +105,56 @@ def test_uniform_hm_no_buffer_bit_identical_to_prepr_engine(problem, method):
 # --------------------------------------------------------------------------- #
 
 
-def _oracle_round(loss, x0, mom0, batch, h_m, lr, momentum):
+def _oracle_round(loss, x0, mom0, batch, h_m, lr, momentum, grad_clip=0.0,
+                  weight_decay=0.0):
     """Per-client Python loop: client m runs h_m[m] heavy-ball SGD steps on
-    its own microbatches, then params (and momentum) are plainly averaged."""
+    its own microbatches, then params (and momentum) are plainly averaged.
+    Each step's gradient is clipped to global norm ``grad_clip`` (0 = off),
+    then ``weight_decay``·x is added. Also returns how many steps clipped."""
     grad = jax.grad(lambda x, mc: loss({"x": x}, mc))
     xs, ms, final_losses = [], [], []
+    n_clipped = 0
     for m in range(len(h_m)):
         x, mo = x0.copy(), mom0[m].copy()
         for h in range(h_m[m]):
             micro = {k: jnp.asarray(v[m, h]) for k, v in batch.items()}
             l = float(loss({"x": jnp.asarray(x)}, micro))
             g = np.asarray(grad(jnp.asarray(x), micro))
+            if grad_clip:
+                nrm = np.sqrt(np.vdot(g, g) + 1e-12)
+                n_clipped += int(nrm > grad_clip)
+                g = g * min(1.0, grad_clip / nrm)
+            g = g + weight_decay * x
             mo = momentum * mo + g
             x = x - lr * mo
         xs.append(x)
         ms.append(mo)
         final_losses.append(l)
     return (np.mean(xs, axis=0), np.mean(ms, axis=0),
-            np.asarray(final_losses))
+            np.asarray(final_losses), n_clipped)
 
 
-@pytest.mark.parametrize("momentum", [0.0, 0.9])
-def test_masked_loop_matches_python_oracle(problem, momentum):
+@pytest.mark.parametrize("momentum,grad_clip,weight_decay", [
+    pytest.param(0.0, 0.0, 0.0, id="0.0"),
+    pytest.param(0.9, 0.0, 0.0, id="0.9"),
+    pytest.param(0.9, 0.3, 0.0, id="clip"),
+    pytest.param(0.9, 0.0, 0.05, id="wd"),
+    pytest.param(0.9, 0.3, 0.05, id="clip-wd"),
+])
+def test_masked_loop_matches_python_oracle(problem, momentum, grad_clip,
+                                           weight_decay):
     """One heterogeneous round (H_m = 1..H) equals the per-client oracle:
     frozen clients contribute their step-H_m state to the sync average, and
-    loss_per_client reports each client's OWN final step."""
+    loss_per_client reports each client's OWN final step — also with the
+    per-step global-norm grad clip and weight decay composed in."""
     H, M = 4, 4
     h_m = (1, 2, 4, 3)
     loss = _quad_loss(problem)
     if momentum:   # savic heavy-ball clients, identity D, momentum averaged
         spec = engine.method_spec("savic", **{**MS_KW, "beta1": momentum},
                                   pc_kind="identity", local_steps=h_m)
+        spec = dataclasses.replace(spec, client=dataclasses.replace(
+            spec.client, grad_clip=grad_clip, weight_decay=weight_decay))
         lr = MS_KW["gamma"]
     else:          # fedavg plain-SGD clients
         spec = engine.method_spec("fedavg", **MS_KW, local_steps=h_m)
@@ -145,9 +166,11 @@ def test_masked_loop_matches_python_oracle(problem, momentum):
     batch = {k: np.asarray(v) for k, v in loader.round_batch(H).items()}
     new_state, met = step(state, jax.tree.map(jnp.asarray, batch),
                           jax.random.PRNGKey(9))
-    x_avg, m_avg, final_losses = _oracle_round(
+    x_avg, m_avg, final_losses, n_clipped = _oracle_round(
         loss, np.zeros(24), np.asarray(state["mom"]["x"]), batch, h_m,
-        lr, momentum)
+        lr, momentum, grad_clip, weight_decay)
+    if grad_clip:
+        assert n_clipped > 0       # the clip really engaged
     np.testing.assert_allclose(np.asarray(new_state["params"]["x"][0]),
                                x_avg, rtol=1e-6, atol=1e-7)
     if momentum:
@@ -180,12 +203,17 @@ def test_uniform_truncated_hm_equals_truncated_batch(problem):
                                   np.asarray(out_u["params"]["x"]))
 
 
-def test_local_scaling_masks_per_client_preconditioner(problem):
-    """local-adam with heterogeneous H_m: a frozen client's per-client D and
-    step counter t freeze too (the D of client m reflects h_m[m] updates)."""
+@pytest.mark.parametrize("method,kw", [
+    ("local-adam", {}),
+    ("savic", dict(pc_kind="oasis", scaling="local")),   # Hutchinson stat
+], ids=["local-adam", "oasis-local"])
+def test_local_scaling_masks_per_client_preconditioner(problem, method, kw):
+    """Local scaling with heterogeneous H_m: a frozen client's per-client D
+    and step counter t freeze too (the D of client m reflects h_m[m]
+    updates), for an Adam-family stat and for a Hutchinson one."""
     H, M = 4, 4
     h_m = (1, 4, 2, 3)
-    spec = engine.method_spec("local-adam", **MS_KW, local_steps=h_m)
+    spec = engine.method_spec(method, **MS_KW, **kw, local_steps=h_m)
     state, met = _run(problem, engine.build_round_step, engine.init_state,
                       spec, rounds=1, H=H, n_clients=M)
     t = np.asarray(state["precond"]["t"])

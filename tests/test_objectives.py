@@ -133,20 +133,6 @@ def test_nonidentity_objective_changes_trajectory(problem):
                               np.asarray(st_b["params"]["x"]))
 
 
-def test_fused_path_bit_identical_under_objective(problem):
-    """The flat-buffer fused loop is grad-source agnostic: with a keyed
-    objective it matches the tree path bit-for-bit (same per-step keys)."""
-    obj = _quad_objective(problem, noise=0.3)
-    mk = lambda fused: engine.method_spec("savic", **MS_KW,
-                                          use_fused_kernel=fused)
-    st_t, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(False), objective=obj)
-    st_f, _ = _run(problem, engine.build_round_step, engine.init_state,
-                   mk(True), objective=obj)
-    np.testing.assert_array_equal(np.asarray(st_t["params"]["x"]),
-                                  np.asarray(st_f["params"]["x"]))
-
-
 # --------------------------------------------------------------------------- #
 # objective math
 # --------------------------------------------------------------------------- #

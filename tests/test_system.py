@@ -210,82 +210,6 @@ def test_serve_driver():
     assert res.per_token_s.shape == (3,)           # gen_len - 1 decode steps
 
 
-def test_dryrun_fused_sharded_artifact_schema():
-    """The dry-run artifact's fused-path keys (DESIGN.md §7) come verbatim
-    from BuiltStep meta (dryrun.run_one copies them): a model-/FSDP-sharded
-    plan keeps ``use_fused_kernel`` and records ``flat_layout_sharded`` with
-    the full per-shard schema — and no ``fused_kernel_fallback``."""
-    from jax.sharding import Mesh
-
-    from repro.configs import ShapeConfig
-    from repro.launch.steps import build_train_step
-
-    dev = np.array(jax.devices("cpu")[:1]).reshape(1, 1)
-    mesh = Mesh(dev, ("data", "model"))
-    shape = ShapeConfig("tiny_train", 32, 2, "train")
-    built = build_train_step("qwen2-0.5b", shape, mesh, method="savic",
-                             mode="plain", reduced=True, h_local=2,
-                             use_fused_kernel=True)
-    assert built.meta["engine_spec"].client.use_fused_kernel
-    assert "fused_kernel_fallback" not in built.meta
-    assert "flat_layout" not in built.meta
-    lay = built.meta["flat_layout_sharded"]
-    assert set(lay) >= {"n_shards", "axes", "axis_sizes", "n_local", "n_flat",
-                        "leaves"}
-    assert lay["n_flat"] == lay["n_shards"] * lay["n_local"]
-    for leaf in lay["leaves"]:
-        assert set(leaf) >= {"path", "global_shape", "local_shape", "size",
-                             "offset", "split", "uneven_fallback"}
-    import json as _json
-    _json.dumps(lay)    # artifact must serialize
-
-
-def test_dryrun_fused_fallback_only_for_non_fp32(monkeypatch):
-    """``fused_kernel_fallback`` survives ONLY for genuinely ineligible
-    builds (non-fp32 client state — the flat view is fp32 by contract);
-    sharded plans are no longer a fallback reason."""
-    from jax.sharding import Mesh
-
-    from repro.configs import ShapeConfig
-    from repro.launch import steps as steps_mod
-    from repro.launch.steps import _fused_non_fp32, build_train_step
-
-    # the helper mirrors the engine's all_float32 trace-time gate
-    f32 = {"x": jax.ShapeDtypeStruct((4,), jnp.float32)}
-    bf16 = {"x": jax.ShapeDtypeStruct((4,), jnp.bfloat16)}
-    spec = savic.engine_spec(PrecondConfig(kind="adam", alpha=1e-2),
-                             SavicConfig(gamma=1e-3, beta1=0.9))
-    base = {"params": f32, "mom": f32,
-            "precond": {"d": f32, "t": jax.ShapeDtypeStruct((), jnp.int32)}}
-    assert _fused_non_fp32(base, spec) == ""
-    assert _fused_non_fp32({**base, "mom": bf16}, spec) == "mom"
-    assert _fused_non_fp32({**base, "precond": {"d": bf16, "t": base[
-        "precond"]["t"]}}, spec) == "precond.d"
-
-    # full launch path: doctor the client state to bf16 -> fallback meta
-    orig = steps_mod.engine.init_state
-
-    def bf16_init(key, init_params_fn, spec, n_clients):
-        st = orig(key, init_params_fn, spec, n_clients)
-        for name in ("params", "mom"):
-            st[name] = jax.tree.map(lambda x: x.astype(jnp.bfloat16),
-                                    st[name])
-        return st
-
-    monkeypatch.setattr(steps_mod.engine, "init_state", bf16_init)
-    dev = np.array(jax.devices("cpu")[:1]).reshape(1, 1)
-    mesh = Mesh(dev, ("data", "model"))
-    shape = ShapeConfig("tiny_train", 32, 2, "train")
-    built = build_train_step("qwen2-0.5b", shape, mesh, method="savic",
-                             mode="plain", reduced=True, h_local=2,
-                             use_fused_kernel=True)
-    assert not built.meta["engine_spec"].client.use_fused_kernel
-    assert "non-fp32 client state (params" \
-        in built.meta["fused_kernel_fallback"]
-    assert "flat_layout_sharded" not in built.meta
-    assert "flat_layout" not in built.meta
-
-
 def test_pairs_to_run_covers_assignment():
     pairs = pairs_to_run()
     archs = {a for a, _ in pairs}

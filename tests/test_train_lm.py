@@ -140,7 +140,6 @@ def test_layers_cuts_depth_and_reports_setup():
     assert s["device"]["platform"] == "cpu" and s["device"]["count"] >= 1
     assert s["compile_s"] > 0 and s["argument_bytes"] > 0
     assert s["pallas_calls"] == 0          # interpret mode on the CPU
-    assert "fused_kernel_fallback" not in s
 
 
 def test_profile_dir_traces_the_rounds_after_compile(tmp_path):
@@ -197,7 +196,7 @@ def test_mesh_path_end_to_end_with_resume(tmp_path):
     from repro.launch import train as train_mod
     argv = ["--arch", "qwen2-0.5b", "--reduced", "--mesh", "debug",
             "--mesh-shape", "1x1", "--method", "local-adam",
-            "--use-fused-kernel", "--h-local", "2", "--batch", "2",
+            "--h-local", "2", "--batch", "2",
             "--seq", "32", "--ckpt", str(tmp_path), "--ckpt-every", "1"]
     log = train_mod.main(argv + ["--rounds", "2"])
     assert len(log) == 2
